@@ -814,8 +814,8 @@ ALL_BENCHES = [bench_s1_throughput_scaling, bench_s2_update_latency,
                bench_train_micro, bench_serve_micro, bench_roofline_summary]
 
 # fast perf-path subset for CI (--smoke): skips the DL train/serve micro
-# rows (their substrate is already compiled by the test suite) and fails
-# the process on any ERROR row so perf-path regressions break CI
+# rows (their substrate is already compiled by the test suite); like a
+# full run, it exits non-zero on any ERROR row
 SMOKE_BENCHES = [bench_s1_throughput_scaling, bench_s2_update_latency,
                  bench_s3_offload, bench_pipeline_partition,
                  bench_pipeline_fuse_xla,
@@ -866,7 +866,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",
-                    help="fast subset + nonzero exit on any ERROR row (CI)")
+                    help="fast subset (CI)")
     ap.add_argument("--only", metavar="SUBSTR", default=None,
                     help="run only bench functions whose name contains "
                          "SUBSTR (e.g. --only sketch)")
@@ -882,7 +882,7 @@ def main(argv=None) -> int:
     for bench in benches:
         try:
             bench(rows, quick)
-        except Exception as e:  # keep the harness green end-to-end
+        except Exception as e:  # report every bench, then fail below
             rows.append((bench.__name__, -1.0, f"ERROR {type(e).__name__}: {e}"))
     print("name,us_per_call,derived")
     for name, us, derived in rows:
@@ -893,12 +893,13 @@ def main(argv=None) -> int:
             f.write("\n")
         print(f"wrote {len(rows)} rows -> {args.out}", file=sys.stderr)
     errors = [r for r in rows if str(r[2]).startswith("ERROR")]
-    if args.smoke and errors:
-        print(f"SMOKE FAILED: {len(errors)} benchmark(s) errored",
-              file=sys.stderr)
+    if errors:
+        print(f"FAILED: {len(errors)} benchmark(s) errored", file=sys.stderr)
         return 1
     return 0
 
 
 if __name__ == '__main__':
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

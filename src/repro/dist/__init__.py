@@ -51,7 +51,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.dist import checkpoint  # noqa: F401  (re-export submodule)
 from repro.dist.api import logical_to_spec, spec_is_replicated
@@ -108,7 +108,8 @@ def _coerce_mesh(mesh) -> Mesh:
             raise ValueError(
                 f"mesh {dict(zip(names, shape))} needs {n} devices, "
                 f"have {len(devs)}")
-        return jax.make_mesh(shape, names, devices=devs[:n])
+        return jax.make_mesh(shape, names, (AxisType.Auto,) * len(shape),
+                             devices=devs[:n])
     return mesh
 
 

@@ -18,10 +18,11 @@ round-trip into one ``pallas_call`` over the flattened tensor:
 
 * :func:`ef_topk_int8_roundtrip` — the composed sparsify-then-quantize
   round-trip with ONE shared residual. Top-k selection is expressed as a
-  magnitude threshold (the k-th largest ``|x + residual|``, found with
-  ``jax.lax.top_k`` on the host side — selection is the one genuinely
-  global, sort-shaped step); the kernel then fuses mask + survivor amax
-  + quantize-dequantize + residual in one pass. For tie-free inputs this
+  magnitude threshold (the k-th largest ``|x + residual|``, found outside
+  the kernel by an exact bisection over its bits — selection is the one
+  genuinely global, sort-shaped step); the kernel then fuses mask +
+  survivor amax + quantize-dequantize + residual in one pass. For
+  tie-free inputs this
   is bitwise the same selection as exact top-k, and the error-feedback
   telescoping identity ``decoded + residual' == x + residual`` holds for
   ANY selection, ties included.
@@ -39,39 +40,83 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _QMAX = 127.0
+_LANES = 128
 
 
-def _blocked_1d(t: jax.Array, block: int):
-    """Flatten + zero-pad to (blocks, block)."""
+def _tiled(t: jax.Array, block: int):
+    """Flatten + zero-pad to a lane-dense ``(rows, 128)`` slab, and pick
+    the row block: about ``block`` elements, a multiple of 8 rows."""
     flat = jnp.ravel(t).astype(jnp.float32)
     size = flat.shape[0]
-    npad = -(-size // block) * block
+    need = -(-size // (8 * _LANES)) * 8
+    rows = min(max(8, -(-block // (8 * _LANES)) * 8), need)
+    npad = -(-need // rows) * rows * _LANES
     if npad != size:
         flat = jnp.pad(flat, (0, npad - size))
-    return flat.reshape(npad // block, block), size
+    return flat.reshape(npad // _LANES, _LANES), rows, size
 
 
-def _int8_kernel(x_ref, r_ref, dec_ref, rout_ref, amax_scr, scale_scr, *,
-                 blocks: int):
+def _kth_largest(a: jax.Array, k: int) -> jax.Array:
+    """Exact k-th largest of a non-negative fp32 vector, by bisection on
+    the bit pattern (non-negative floats order like their int32 bits):
+    31 counting passes, where a sort-based top-k compiles for half a
+    minute on the chip."""
+    bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+
+    def step(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(jnp.sum(bits >= cand) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 31, step, jnp.int32(0))
+    return jax.lax.bitcast_convert_type(t, jnp.float32)
+
+
+def _amax(v: jax.Array) -> jax.Array:
+    """Max over a 2-D tile, kept (1, 1)."""
+    return jnp.max(jnp.max(v, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _roundtrip(kernel, residual, x, block, interpret, *extra):
+    """Run a two-phase round-trip kernel over row blocks of the flattened
+    payload: phase 0 reduces the amax into a (1, 1) VMEM scratch, phase 1
+    writes ``(decoded, residual')``. Phase 0 parks on the outputs' first
+    block, so nothing unwritten is flushed. ``extra`` are (1, 1) inputs."""
+    xb, rows, size = _tiled(x, block)
+    rb, _, _ = _tiled(residual, block)
+    blocks = xb.shape[0] // rows
+    tile = pl.BlockSpec((rows, _LANES), lambda p, b: (b, 0))
+    out = pl.BlockSpec((rows, _LANES), lambda p, b: (b * p, 0))
+    scalar = pl.BlockSpec((1, 1), lambda p, b: (0, 0))
+    dec, rout = pl.pallas_call(
+        functools.partial(kernel, blocks=blocks),
+        grid=(2, blocks),
+        in_specs=[tile, tile] + [scalar] * len(extra),
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct(xb.shape, jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
+        interpret=interpret,
+    )(xb, rb, *(e.reshape(1, 1) for e in extra))
+    shape = jnp.shape(x)
+    return (dec.reshape(-1)[:size].reshape(shape).astype(x.dtype),
+            rout.reshape(-1)[:size].reshape(shape))
+
+
+def _int8_kernel(x_ref, r_ref, dec_ref, rout_ref, amax_scr, *, blocks: int):
     phase = pl.program_id(0)
     bi = pl.program_id(1)
-    xc = x_ref[...] + r_ref[...]                          # (1, block)
+    xc = x_ref[...] + r_ref[...]                          # (rows, 128)
 
     @pl.when(phase == 0)
     def _reduce():
         @pl.when(bi == 0)
         def _init():
-            amax_scr[0, 0] = 0.0
+            amax_scr[...] = jnp.zeros_like(amax_scr)
 
-        amax_scr[0, 0] = jnp.maximum(amax_scr[0, 0], jnp.max(jnp.abs(xc)))
-
-        @pl.when(bi == blocks - 1)
-        def _scale():
-            scale_scr[0, 0] = jnp.maximum(amax_scr[0, 0], 1e-30) / _QMAX
+        amax_scr[...] = jnp.maximum(amax_scr[...], _amax(jnp.abs(xc)))
 
     @pl.when(phase == 1)
-    def _roundtrip():
-        scale = scale_scr[0, 0]
+    def _emit():
+        scale = jnp.maximum(amax_scr[...], 1e-30) / _QMAX
         q = jnp.clip(jnp.round(xc / scale), -_QMAX, _QMAX)
         dec = q * scale
         dec_ref[...] = dec
@@ -79,62 +124,33 @@ def _int8_kernel(x_ref, r_ref, dec_ref, rout_ref, amax_scr, scale_scr, *,
 
 
 def ef_int8_roundtrip(residual: jax.Array, x: jax.Array, *,
-                      block: int = 2048, interpret: bool = False):
+                      block: int = 65536, interpret: bool = False):
     """Fused int8 EF wire round-trip: ``(decoded, new_residual)``.
 
     Agrees with ``dist.compression.ef_roundtrip`` to <=1 ulp; the
     internal EF identity is exact."""
-    xb, size = _blocked_1d(x, block)
-    rb, _ = _blocked_1d(residual, block)
-    blocks = xb.shape[0]
-    kernel = functools.partial(_int8_kernel, blocks=blocks)
-    dec, rout = pl.pallas_call(
-        kernel,
-        grid=(2, blocks),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(xb.shape, jnp.float32),
-            jax.ShapeDtypeStruct(xb.shape, jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)],
-        interpret=interpret,
-    )(xb, rb)
-    shape = jnp.shape(x)
-    return (dec.reshape(-1)[:size].reshape(shape).astype(x.dtype),
-            rout.reshape(-1)[:size].reshape(shape))
+    return _roundtrip(_int8_kernel, residual, x, block, interpret)
 
 
-def _topk_int8_kernel(x_ref, r_ref, t_ref, dec_ref, rout_ref,
-                      amax_scr, scale_scr, *, blocks: int):
+def _topk_int8_kernel(x_ref, r_ref, t_ref, dec_ref, rout_ref, amax_scr, *,
+                      blocks: int):
     phase = pl.program_id(0)
     bi = pl.program_id(1)
-    xc = x_ref[...] + r_ref[...]                          # (1, block)
-    kept = jnp.abs(xc) >= t_ref[0, 0]
+    xc = x_ref[...] + r_ref[...]                          # (rows, 128)
+    kept = jnp.abs(xc) >= t_ref[...]
 
     @pl.when(phase == 0)
     def _reduce():
         @pl.when(bi == 0)
         def _init():
-            amax_scr[0, 0] = 0.0
+            amax_scr[...] = jnp.zeros_like(amax_scr)
 
-        amax_scr[0, 0] = jnp.maximum(
-            amax_scr[0, 0], jnp.max(jnp.where(kept, jnp.abs(xc), 0.0)))
-
-        @pl.when(bi == blocks - 1)
-        def _scale():
-            scale_scr[0, 0] = jnp.maximum(amax_scr[0, 0], 1e-30) / _QMAX
+        amax_scr[...] = jnp.maximum(
+            amax_scr[...], _amax(jnp.where(kept, jnp.abs(xc), 0.0)))
 
     @pl.when(phase == 1)
-    def _roundtrip():
-        scale = scale_scr[0, 0]
+    def _emit():
+        scale = jnp.maximum(amax_scr[...], 1e-30) / _QMAX
         q = jnp.clip(jnp.round(jnp.where(kept, xc, 0.0) / scale),
                      -_QMAX, _QMAX)
         dec = jnp.where(kept, q * scale, 0.0)
@@ -143,7 +159,7 @@ def _topk_int8_kernel(x_ref, r_ref, t_ref, dec_ref, rout_ref,
 
 
 def ef_topk_int8_roundtrip(residual: jax.Array, x: jax.Array, k: int, *,
-                           block: int = 2048, interpret: bool = False):
+                           block: int = 65536, interpret: bool = False):
     """Fused top-k + int8 EF wire round-trip with one shared residual.
 
     Keeps the coordinates of ``x + residual`` whose magnitude reaches the
@@ -151,34 +167,7 @@ def ef_topk_int8_roundtrip(residual: jax.Array, x: jax.Array, k: int, *,
     and carries dropped mass AND quantization error forward:
     ``(decoded, new_residual)``."""
     xc = jnp.ravel(x).astype(jnp.float32) + jnp.ravel(residual)
-    size = xc.shape[0]
-    k = max(1, min(int(k), size))
-    # the selection threshold — the one sort-shaped global step
-    t = jax.lax.top_k(jnp.abs(xc), k)[0][-1]
-    xb, _ = _blocked_1d(x, block)
-    rb, _ = _blocked_1d(residual, block)
-    blocks = xb.shape[0]
-    kernel = functools.partial(_topk_int8_kernel, blocks=blocks)
-    dec, rout = pl.pallas_call(
-        kernel,
-        grid=(2, blocks),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda p, b: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, block), lambda p, b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(xb.shape, jnp.float32),
-            jax.ShapeDtypeStruct(xb.shape, jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)],
-        interpret=interpret,
-    )(xb, rb, t.reshape(1, 1))
-    shape = jnp.shape(x)
-    return (dec.reshape(-1)[:size].reshape(shape).astype(x.dtype),
-            rout.reshape(-1)[:size].reshape(shape))
+    k = max(1, min(int(k), xc.shape[0]))
+    # the selection threshold: the one global, sort-shaped step
+    t = _kth_largest(jnp.abs(xc), k)
+    return _roundtrip(_topk_int8_kernel, residual, x, block, interpret, t)

@@ -1,14 +1,14 @@
 """Mamba selective scan as a chunked Pallas TPU kernel.
 
 Grid: (B, d_inner blocks, chunks) with chunks innermost-sequential; the
-running state h (bd, N) persists in VMEM scratch. Within a chunk the scan
-is evaluated by a cumulative-product formulation entirely in VMEM:
+running state h, held as (N, bd), persists in VMEM scratch. Within a chunk
+the recurrence is swept step by step entirely in VMEM:
 
     h_t = a_t h_{t-1} + b_t,  a_t = exp(dt_t * A)
 
-Per-chunk working set at Lc=128, bd=256, N=16: a/b tiles (Lc,bd,N) f32
-~= 4 MB — VMEM-sized by construction (that's the reason for chunking: the
-(B,S,dI,N) tensor of the naive parallel scan would be HBM-resident).
+Per-chunk working set at Lc=128, bd=256, N=16: dt/x tiles (Lc, bd) f32
+plus B/C tiles (N, Lc) ~= 0.3 MB — the (B,S,dI,N) tensor of the naive
+parallel scan never exists.
 """
 
 from __future__ import annotations
@@ -21,9 +21,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _mamba_kernel(dt_ref, x_ref, B_ref, C_ref, A_ref, h0_ref, y_ref,
-                  hout_ref, h_scr, *, chunks: int, chunk: int, bd: int,
-                  n: int):
+def _mamba_kernel(dt_ref, x_ref, bt_ref, ct_ref, at_ref, h0_ref, y_ref,
+                  hout_ref, h_scr, *, chunks: int, chunk: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -31,35 +30,17 @@ def _mamba_kernel(dt_ref, x_ref, B_ref, C_ref, A_ref, h0_ref, y_ref,
         h_scr[...] = h0_ref[0]
 
     dt = dt_ref[0].astype(jnp.float32)        # (Lc, bd)
-    x = x_ref[0].astype(jnp.float32)          # (Lc, bd)
-    Bm = B_ref[0].astype(jnp.float32)         # (Lc, N)
-    Cm = C_ref[0].astype(jnp.float32)         # (Lc, N)
-    A = A_ref[0].astype(jnp.float32)          # (bd, N)
-
-    a = jnp.exp(dt[:, :, None] * A[None])     # (Lc, bd, N)
-    b = (dt * x)[:, :, None] * Bm[:, None, :]
-
-    # in-chunk associative scan via cumulative log-products:
-    # h_t = P_t * (h_0 + sum_{s<=t} b_s / P_s), P_t = prod_{s<=t} a_s.
-    # Stable form: logP is a cumsum of negatives; b_s/P_s can overflow, so
-    # use the scan-free two-pass with renormalization by P_t directly:
-    logP = jnp.cumsum(dt[:, :, None] * A[None], axis=0)   # (Lc,bd,N) <= 0
-    P = jnp.exp(logP)
-    # sum_{s<=t} b_s * exp(logP_t - logP_s)  — pairwise would be (Lc,Lc,..);
-    # instead do a short sequential fori over the chunk (VMEM-resident).
-    h = h_scr[...]
-
-    def step(t, carry):
-        h_c, y_acc = carry
-        h_c = a[t] * h_c + b[t]
-        y_t = jnp.sum(h_c * Cm[t][None, :], axis=-1)      # (bd,)
-        y_acc = jax.lax.dynamic_update_index_in_dim(y_acc, y_t, t, 0)
-        return h_c, y_acc
-
-    y0 = jnp.zeros((chunk, bd), jnp.float32)
-    h, y = jax.lax.fori_loop(0, chunk, step, (h, y0))
-    del P, logP
-    y_ref[0] = y.astype(y_ref.dtype)
+    dx = dt * x_ref[0].astype(jnp.float32)    # (Lc, bd)
+    bt = bt_ref[0].astype(jnp.float32)        # (N, Lc)
+    ct = ct_ref[0].astype(jnp.float32)        # (N, Lc)
+    at = at_ref[...].astype(jnp.float32)      # (N, bd)
+    h = h_scr[...]                            # (N, bd)
+    # a short sequential sweep over the chunk, unrolled with static slices
+    # (VMEM-resident; the chip's kernel compiler indexes values statically)
+    for t in range(chunk):
+        h = jnp.exp(at * dt[t:t + 1]) * h + bt[:, t:t + 1] * dx[t:t + 1]
+        y_ref[0, t:t + 1, :] = jnp.sum(h * ct[:, t:t + 1], axis=0,
+                                       keepdims=True).astype(y_ref.dtype)
     h_scr[...] = h
 
     @pl.when(ci == chunks - 1)
@@ -70,7 +51,10 @@ def _mamba_kernel(dt_ref, x_ref, B_ref, C_ref, A_ref, h0_ref, y_ref,
 def mamba_scan_bd(dt, x, Bm, Cm, A, h0, *, chunk: int = 128, bd: int = 256,
                   interpret: bool = False):
     """dt,x: (B, S, dI); Bm,Cm: (B, S, N); A: (dI, N); h0: (B, dI, N) fp32.
-    Returns (y (B,S,dI) fp32, h_last (B,dI,N) fp32)."""
+    Returns (y (B,S,dI) fp32, h_last (B,dI,N) fp32).
+
+    Inside the kernel the state is held transposed, ``(N, bd)``, so the
+    inner dim rides lanes; B and C come in as ``(N, S)``."""
     B, S, dI = dt.shape
     N = Bm.shape[-1]
     bd = min(bd, dI)
@@ -83,28 +67,28 @@ def mamba_scan_bd(dt, x, Bm, Cm, A, h0, *, chunk: int = 128, bd: int = 256,
         Bm = jnp.pad(Bm, ((0, 0), (0, Sp - S), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, Sp - S), (0, 0)))
     chunks = Sp // chunk
-    kernel = functools.partial(_mamba_kernel, chunks=chunks, chunk=chunk,
-                               bd=bd, n=N)
+    kernel = functools.partial(_mamba_kernel, chunks=chunks, chunk=chunk)
     y, h_last = pl.pallas_call(
         kernel,
         grid=(B, dI // bd, chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, bd), lambda b, d, c: (b, c, d)),
             pl.BlockSpec((1, chunk, bd), lambda b, d, c: (b, c, d)),
-            pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
-            pl.BlockSpec((1, bd, N), lambda b, d, c: (0, d, 0)),
-            pl.BlockSpec((1, bd, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((1, N, chunk), lambda b, d, c: (b, 0, c)),
+            pl.BlockSpec((1, N, chunk), lambda b, d, c: (b, 0, c)),
+            pl.BlockSpec((N, bd), lambda b, d, c: (0, d)),
+            pl.BlockSpec((1, N, bd), lambda b, d, c: (b, 0, d)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, bd), lambda b, d, c: (b, c, d)),
-            pl.BlockSpec((1, bd, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((1, N, bd), lambda b, d, c: (b, 0, d)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sp, dI), jnp.float32),
-            jax.ShapeDtypeStruct((B, dI, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, N, dI), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
         interpret=interpret,
-    )(dt, x, Bm, Cm, A[None], h0)
-    return y[:, :S, :], h_last
+    )(dt, x, Bm.transpose(0, 2, 1), Cm.transpose(0, 2, 1), A.T,
+      h0.transpose(0, 2, 1))
+    return y[:, :S, :], h_last.transpose(0, 2, 1)

@@ -1,10 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Kernel dispatch policy: the Pallas path is taken on TPU backends (or when
-``REPRO_FORCE_PALLAS_INTERPRET=1`` forces interpret mode, used by tests and
-CPU benchmarks); otherwise callers fall back to the XLA chunked
-implementations. This keeps one model code path across dev CPU and
-production TPU.
+Kernel dispatch policy: on a TPU backend the Pallas path is taken and the
+kernels always compile for the chip. On the CPU, ``JAX_PALLAS_INTERPRET=1``
+(or ``REPRO_FORCE_PALLAS_INTERPRET=1``) runs them in Pallas interpret mode,
+which is how the tests exercise them; otherwise callers use the jnp / XLA
+implementations. Interpret mode is never taken on a TPU, whatever the
+environment says.
 """
 
 from __future__ import annotations
@@ -25,7 +26,10 @@ from repro.kernels import rwkv6_wkv as _wkv
 
 def _interpret() -> bool:
     # JAX_PALLAS_INTERPRET is the conventional spelling the CI oracle job
-    # uses; REPRO_FORCE_PALLAS_INTERPRET kept for back-compat.
+    # uses; REPRO_FORCE_PALLAS_INTERPRET kept for back-compat. Neither
+    # applies on a TPU: there the kernels run compiled.
+    if jax.default_backend() == "tpu":
+        return False
     return (os.environ.get("REPRO_FORCE_PALLAS_INTERPRET", "0") == "1"
             or os.environ.get("JAX_PALLAS_INTERPRET", "0") == "1")
 
@@ -66,13 +70,13 @@ def mamba_scan(dt, x, Bm, Cm, A, h0, *, chunk: int = 128, bd: int = 256):
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "width", "block"))
-def countmin_update(ids, *, depth: int, width: int, seeds, block: int = 1024):
+def countmin_update(ids, *, depth: int, width: int, seeds, block: int = 512):
     return _cms.countmin_update(ids, depth, width, seeds, block=block,
                                 interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
-def countmin_update_query(ids, table, seeds, *, block: int = 1024):
+def countmin_update_query(ids, table, seeds, *, block: int = 512):
     return _cms.countmin_update_query(ids, table, seeds, block=block,
                                       interpret=_interpret())
 
@@ -91,12 +95,12 @@ def hash_features(ids, vals, *, dim: int, seed: int = 17, block: int = 256):
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
-def ef_int8_roundtrip(residual, x, *, block: int = 2048):
+def ef_int8_roundtrip(residual, x, *, block: int = 65536):
     return _ef.ef_int8_roundtrip(residual, x, block=block,
                                  interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block"))
-def ef_topk_int8_roundtrip(residual, x, *, k: int, block: int = 2048):
+def ef_topk_int8_roundtrip(residual, x, *, k: int, block: int = 65536):
     return _ef.ef_topk_int8_roundtrip(residual, x, k, block=block,
                                       interpret=_interpret())

@@ -39,16 +39,20 @@ _HASH_P = 2_147_483_647
 _HASH_C = 0x9E37
 
 
-def _normalize_kernel(x_ref, n0_ref, mean0_ref, m20_ref,
-                      y_ref, n1_ref, mean1_ref, m21_ref,
-                      s1_scr, s2_scr, stat_scr, *,
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _normalize_kernel(n0_ref, x_ref, mean0_ref, m20_ref,
+                      y_ref, mean1_ref, m21_ref,
+                      s1_scr, s2_scr, mean_scr, rstd_scr, *,
                       blocks: int, block: int, n: int, impute: bool):
     phase = pl.program_id(0)
     bi = pl.program_id(1)
-    mean0 = mean0_ref[0]                                  # (d,)
+    mean0 = mean0_ref[...]                                # (1, d)
     x = x_ref[...]                                        # (block, d)
     if impute:
-        x = jnp.where(jnp.isnan(x), mean0[None, :], x)
+        x = jnp.where(jnp.isnan(x), mean0, x)
     valid = (bi * block
              + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)) < n
     xm = jnp.where(valid, x, 0.0)
@@ -60,8 +64,8 @@ def _normalize_kernel(x_ref, n0_ref, mean0_ref, m20_ref,
             s1_scr[...] = jnp.zeros_like(s1_scr)
             s2_scr[...] = jnp.zeros_like(s2_scr)
 
-        s1_scr[...] = s1_scr[...] + jnp.sum(xm, axis=0)
-        s2_scr[...] = s2_scr[...] + jnp.sum(xm * xm, axis=0)
+        s1_scr[...] += jnp.sum(xm, axis=0, keepdims=True)
+        s2_scr[...] += jnp.sum(xm * xm, axis=0, keepdims=True)
 
         @pl.when(bi == blocks - 1)
         def _merge():
@@ -75,18 +79,17 @@ def _normalize_kernel(x_ref, n0_ref, mean0_ref, m20_ref,
             n1 = n0 + nb
             delta = mean_b - mean0
             mean1 = mean0 + delta * (nb / jnp.maximum(n1, 1.0))
-            m21 = (m20_ref[0] + m2_b
+            m21 = (m20_ref[...] + m2_b
                    + delta * delta * n0 * nb / jnp.maximum(n1, 1.0))
             var = m21 / jnp.maximum(n1 - 1.0, 1.0)
-            stat_scr[0] = mean1
-            stat_scr[1] = jax.lax.rsqrt(var + 1e-6)
-            n1_ref[0, 0] = n1
-            mean1_ref[0] = mean1
-            m21_ref[0] = m21
+            mean_scr[...] = mean1
+            rstd_scr[...] = jax.lax.rsqrt(var + 1e-6)
+            mean1_ref[...] = mean1
+            m21_ref[...] = m21
 
     @pl.when(phase == 1)
     def _normalize():
-        y_ref[...] = (x - stat_scr[0][None, :]) * stat_scr[1][None, :]
+        y_ref[...] = (x - mean_scr[...]) * rstd_scr[...]
 
 
 def fused_normalize(x: jax.Array, n0: jax.Array, mean0: jax.Array,
@@ -100,45 +103,39 @@ def fused_normalize(x: jax.Array, n0: jax.Array, mean0: jax.Array,
     ``ref.fused_normalize_ref`` (= impute_with_mean + norm_update_apply).
     """
     n, d = x.shape
-    block = min(block, max(n, 8))
-    npad = -(-n // block) * block
+    block = min(_round_up(block, 8), _round_up(n, 8))
+    npad = _round_up(n, block)
     if npad != n:
         x = jnp.pad(x, ((0, npad - n), (0, 0)))
     blocks = npad // block
+    n0 = jnp.asarray(n0, jnp.float32)
     kernel = functools.partial(_normalize_kernel, blocks=blocks, block=block,
                                n=n, impute=impute)
-    y, n1, mean1, m21 = pl.pallas_call(
+    row = pl.BlockSpec((1, d), lambda p, b: (0, 0))
+    y, mean1, m21 = pl.pallas_call(
         kernel,
         grid=(2, blocks),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block, d), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda p, b: (0, 0)),
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),
+            row, row,
         ],
-        out_specs=[
-            pl.BlockSpec((block, d), lambda p, b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda p, b: (0, 0)),
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),
-            pl.BlockSpec((1, d), lambda p, b: (0, 0)),
-        ],
+        # phase 0 parks on y's first block, so nothing unwritten is
+        # flushed before phase 1 fills each block
+        out_specs=[pl.BlockSpec((block, d), lambda p, b: (b * p, 0)),
+                   row, row],
         out_shape=[
             jax.ShapeDtypeStruct((npad, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((d,), jnp.float32),
-            pltpu.VMEM((d,), jnp.float32),
-            pltpu.VMEM((2, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32) for _ in range(4)],
         interpret=interpret,
-    )(x.astype(jnp.float32),
-      jnp.asarray(n0, jnp.float32).reshape(1, 1),
+    )(n0.reshape(1, 1),
+      x.astype(jnp.float32),
       jnp.asarray(mean0, jnp.float32)[None, :],
       jnp.asarray(m20, jnp.float32)[None, :])
-    return y[:n], n1[0, 0], mean1[0], m21[0]
+    return y[:n], n0 + n, mean1[0], m21[0]
 
 
 def _hash_kernel(ids_ref, vals_ref, out_ref, *, dim: int, f: int, a: int):

@@ -114,15 +114,22 @@ def fused_normalize_ref(x, n0, mean0, m20, *, impute=True):
 
 def hash_features_ref(ids, vals, dim, seed=17):
     """Signed feature hashing oracle (scatter-add form): ids/vals (n, f)
-    -> dense (n, dim). Same int32 hash as streams/preprocess."""
+    -> dense (n, dim). Same int32 hash as streams/preprocess.
+
+    One scatter per feature column, so colliding features add in feature
+    order on every backend (a single scatter leaves the order of
+    duplicate-index updates to the backend)."""
     a = 2 * seed + 1
     h = (ids.astype(jnp.int32) * a + 0x9E37) % 2_147_483_647
     slot = h % dim
     sign = jnp.where((h // dim) % 2 == 0, 1.0, -1.0)
+    upd = vals.astype(jnp.float32) * sign
     n, f = ids.shape
+    rows = jnp.arange(n)
     out = jnp.zeros((n, dim), jnp.float32)
-    return out.at[jnp.arange(n)[:, None], slot].add(
-        vals.astype(jnp.float32) * sign)
+    for j in range(f):
+        out = out.at[rows, slot[:, j]].add(upd[:, j])
+    return out
 
 
 def ef_int8_roundtrip_ref(residual, x):

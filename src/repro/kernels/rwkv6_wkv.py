@@ -34,10 +34,17 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, h0_ref, o_ref, hout_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)        # log-decay <= 0
-    u = u_ref[0, 0].astype(jnp.float32)       # (hs,)
+    u = u_ref[0].astype(jnp.float32)          # (1, hs)
     h = h_scr[...]                            # (hs, hs)
 
-    L = jnp.cumsum(lw, axis=0)                # inclusive
+    # inclusive cumsum over the chunk as a lower-triangular matmul (the
+    # chip's kernel compiler has no cumsum)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = jnp.where(s_idx <= t_idx, 1.0, 0.0)
+    L = jax.lax.dot_general(tril, lw, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     L_excl = L - lw
     # inter-chunk: (r_t * exp(L_excl_t)) @ S
     q_in = r * jnp.exp(L_excl)
@@ -45,21 +52,24 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, h0_ref, o_ref, hout_ref,
                             preferred_element_type=jnp.float32)
     # intra-chunk pairwise-stable
     dpair = jnp.exp(jnp.minimum(L_excl[:, None, :] - L[None, :, :], 0.0))
-    scores = jnp.einsum("ti,tsi,si->ts", r, dpair, k)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    scores = jnp.sum(r[:, None, :] * dpair * k[None, :, :], axis=-1)
     scores = jnp.where(s_idx < t_idx, scores, 0.0)
     o = o + jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     # diagonal bonus
-    diag = jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)
     o = o + diag * v
     o_ref[0] = o.astype(o_ref.dtype)
 
     # state update
-    L_end = L[-1]                             # (hs,)
-    kdec = k * jnp.exp(L_end[None, :] - L)
-    h_new = jnp.exp(L_end)[:, None] * h + jax.lax.dot_general(
+    L_end = L[chunk - 1:chunk]                # (1, hs)
+    # the same total decay as a column, to scale the rows of S
+    L_end_col = jax.lax.dot_general(
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)   # (hs, 1)
+    kdec = k * jnp.exp(L_end - L)
+    h_new = jnp.exp(L_end_col) * h + jax.lax.dot_general(
         kdec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     h_scr[...] = h_new
 

@@ -13,6 +13,7 @@ import numpy as np
 
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
+    from jax.sharding import AxisType
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
@@ -22,15 +23,17 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py does this automatically)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices[:n])
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests)."""
     import jax
+    from jax.sharding import AxisType
     n = data * model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n])
+                         (AxisType.Auto,) * 2, devices=jax.devices()[:n])
 
 
 def make_elastic_mesh(prefer_model: int = 1, failed=()):

@@ -25,10 +25,12 @@ def main():
     import numpy as np
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model_zoo as zoo
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.sampling import SamplingParams
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     print(f"arch={cfg.name} params={zoo.param_count(cfg)/1e6:.1f}M")
     params = zoo.init_params(cfg, 0)

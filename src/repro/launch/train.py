@@ -2,9 +2,10 @@
 arch's recipe, and runs the streaming train loop with async checkpointing
 and drift-adaptive control.
 
-On this CPU container it runs reduced configs (``--smoke``); on a pod the
-same entrypoint runs the full config (remove --smoke, point JAX at the
-TPU runtime). The step function is identical to the dry-run cells.
+On a CPU host it runs the reduced configs (``--smoke``), with forced host
+devices standing in for a mesh. On a TPU host the same entrypoint runs the
+published config (drop ``--smoke``); JAX finds the chips itself. The step
+function is identical to the dry-run cells.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke \
       --steps 50 --batch 8 --seq 64
@@ -87,12 +88,14 @@ def main():
 
     from repro.configs import get_config
     from repro.dist import checkpoint as ckpt
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import mesh_context
     from repro.models import model_zoo as zoo
     from repro.streams.generators import DriftSpec, TokenStream
     from repro.train.optim import make_optimizer
     from repro.train.train_step import make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.recipe:
         cfg = cfg.with_overrides(recipe=args.recipe)

@@ -6,7 +6,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
 from repro.dist import (
     axis_size, mesh_active, pin_params, shard, shard_param, use_mesh,
@@ -330,7 +330,7 @@ def test_make_elastic_mesh_survives_failures():
 
 
 def test_reshard_tree_roundtrip():
-    mesh = jax.make_mesh((2, 2), ("data", "model"),
+    mesh = jax.make_mesh((2, 2), ("data", "model"), (AxisType.Auto,) * 2,
                          devices=jax.devices()[:4])
     tree = {"w": jnp.arange(32.0).reshape(8, 4), "b": jnp.ones((5,))}
     axes = {"w": ("embed", "ff"), "b": ("embed",)}   # 5 % 2 -> replicated

@@ -42,6 +42,7 @@ def test_sharded_train_step_matches_single_device():
     same params as unsharded execution."""
     out = _run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.dist import use_mesh
         from repro.dist.sharding import build_rules
@@ -59,7 +60,7 @@ def test_sharded_train_step_matches_single_device():
         step_fn = make_train_step(cfg, opt, microbatches=1)
         p1, *_ = jax.jit(step_fn)(params, state, jnp.asarray(0), batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
         rules = build_rules(cfg)
         with use_mesh(mesh, rules):
             p2, *_ = jax.jit(step_fn)(params, state, jnp.asarray(0), batch)
@@ -74,8 +75,9 @@ def test_sharded_train_step_matches_single_device():
 def test_elastic_recovery_after_failure():
     out = _run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.dist import elastic
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
         new = elastic.rebuild_mesh(list(mesh.devices.flat), failed=[3, 5],
                                    prefer_model=2)
         assert new.devices.size == 4, new.devices.size
@@ -94,14 +96,13 @@ def test_compressed_allreduce_matches_mean():
     out = _run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.dist.compression import compressed_allreduce_mean
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), (AxisType.Auto,))
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(8, 64)).astype(np.float32))
 
-        @partial(shard_map, mesh=mesh, in_specs=P("data"),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                  out_specs=(P("data"), P("data")))
         def f(xs):
             m, err = compressed_allreduce_mean(xs[0], "data")
@@ -165,6 +166,7 @@ def test_dryrun_single_cell_small_mesh():
     reduced arch over (2,4) and extract scan-aware roofline terms."""
     out = _run_with_devices("""
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.configs.base import InputShape
         from repro.dist import use_mesh
@@ -177,7 +179,7 @@ def test_dryrun_single_cell_small_mesh():
         cfg = get_config("granite-moe-1b-a400m", smoke=True).with_overrides(
             recipe="ep_fsdp")
         shape = InputShape("tiny_train", 32, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
         rules = build_rules(cfg, shape=shape)
         opt = make_optimizer(cfg, "adamw")
         ts = make_train_step(cfg, opt, microbatches=1)
