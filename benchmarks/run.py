@@ -785,22 +785,6 @@ def bench_membership(rows, quick):
                  f"rescales={orch.elastic.rescales}"))
 
 
-def bench_roofline_summary(rows, quick):
-    """Surface the dry-run roofline verdicts (if the sweep has run)."""
-    try:
-        from repro.launch.report import table
-        t = table()
-        if t:
-            fits = sum(r["fits"] for r in t)
-            rows.append(("dryrun_cells_fit", 0.0,
-                         f"{fits}/{len(t)} cells <=16GiB"))
-            best = max((r for r in t if r["ok"]), key=lambda r: r["frac"])
-            rows.append(("dryrun_best_fraction", 0.0,
-                         f"{best['arch']}x{best['shape']}={best['frac']:.3f}"))
-    except Exception as e:  # table absent before the sweep
-        rows.append(("dryrun_cells_fit", 0.0, f"no sweep: {e}"))
-
-
 ALL_BENCHES = [bench_s1_throughput_scaling, bench_s2_update_latency,
                bench_s3_offload, bench_pipeline_partition,
                bench_pipeline_fuse_xla,
@@ -811,7 +795,7 @@ ALL_BENCHES = [bench_s1_throughput_scaling, bench_s2_update_latency,
                bench_serve_prefill_edge_decode, bench_train_op_placed,
                bench_s4_feature_matrix, bench_generators, bench_sketches,
                bench_kernel_dispatch,
-               bench_train_micro, bench_serve_micro, bench_roofline_summary]
+               bench_train_micro, bench_serve_micro]
 
 # fast perf-path subset for CI (--smoke): skips the DL train/serve micro
 # rows (their substrate is already compiled by the test suite); like a

@@ -44,6 +44,7 @@ from repro.core.placement import (Objective, place, place_frontier,
                                   stale_pools)
 from repro.core.sla import SLA, SLATracker
 from repro.core.sla import codec_candidates as sla_codec_candidates
+from repro.core.spans import span
 
 
 @dataclass
@@ -265,43 +266,45 @@ class OffloadController:
         if reason is None:
             reason = ("sla" if sla is not None and not sla.ok() else
                       "rate_up" if rate > self.planned_rate else "rate_down")
-        old_identity = self._identity(self.assignment, self.codec)
-        old_assign = dict(self.assignment)
-        if self._adaptive and \
-                step - self._last_codec_change >= self.codec_cooldown:
-            plan, frontier = self._replan_codecs(rate, sla)
-        else:
-            plan, frontier = self._plan(rate)
-        new_codec = plan.uplink_codec or self.codec
-        if new_codec != self.codec:
-            self.codec = new_codec
-            self._last_codec_change = step
-        mig = MigrationCost()
-        if self._identity(plan.assignment, self.codec) != old_identity:
-            self._last_change = step
-            # price the state move this adoption implies (ops whose pool
-            # changed ship their resident bytes over the old->new link)
-            mig = migration_cost(self.ops, old_assign, plan.assignment,
-                                 self.resources)
-        self.planned_rate, self.frontier = rate, frontier
-        self.assignment = dict(plan.assignment)
-        self.cut = len(frontier)
-        d = self._decide(step, rate, reason, plan, frontier)
-        d.migration = mig
-        self.history.append(d)
-        return d
+        with span("control.replan", step=step, reason=reason):
+            old_identity = self._identity(self.assignment, self.codec)
+            old_assign = dict(self.assignment)
+            if self._adaptive and \
+                    step - self._last_codec_change >= self.codec_cooldown:
+                plan, frontier = self._replan_codecs(rate, sla)
+            else:
+                plan, frontier = self._plan(rate)
+            new_codec = plan.uplink_codec or self.codec
+            if new_codec != self.codec:
+                self.codec = new_codec
+                self._last_codec_change = step
+            mig = MigrationCost()
+            if self._identity(plan.assignment, self.codec) != old_identity:
+                self._last_change = step
+                # price the state move this adoption implies (ops whose pool
+                # changed ship their resident bytes over the old->new link)
+                mig = migration_cost(self.ops, old_assign, plan.assignment,
+                                     self.resources)
+            self.planned_rate, self.frontier = rate, frontier
+            self.assignment = dict(plan.assignment)
+            self.cut = len(frontier)
+            d = self._decide(step, rate, reason, plan, frontier)
+            d.migration = mig
+            self.history.append(d)
+            return d
 
     def observe(self, step: int, rate: float,
                 sla: Optional[SLATracker] = None) -> OffloadDecision:
         """Called periodically with the measured ingest rate."""
-        if not self.history:
-            # observe() before initial_plan() used to IndexError on
-            # history[-1]; take the initial plan lazily instead
-            return self.initial_plan(rate, step=step)
-        reason = self.wants_replan(step, rate, sla)
-        if reason is None:
-            return self.hold_decision(step, rate)
-        return self.replan(step, rate, sla, reason)
+        with span("control.observe", step=step):
+            if not self.history:
+                # observe() before initial_plan() used to IndexError on
+                # history[-1]; take the initial plan lazily instead
+                return self.initial_plan(rate, step=step)
+            reason = self.wants_replan(step, rate, sla)
+            if reason is None:
+                return self.hold_decision(step, rate)
+            return self.replan(step, rate, sla, reason)
 
     def migrations(self) -> int:
         ids = [(tuple(sorted(d.assignment.items())), d.codec)

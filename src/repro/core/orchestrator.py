@@ -51,6 +51,7 @@ from repro.core.offload import OffloadController
 from repro.core.pipeline import OpGraph, Pipeline, standard_stream_pipeline
 from repro.core.placement import Objective
 from repro.core.sla import SLA, SLATracker, codec_candidates, pick_codec
+from repro.core.spans import span
 from repro.dist import elastic
 
 
@@ -264,9 +265,10 @@ class Orchestrator:
 
     # -- drift response: each op declares its own -------------------------
     def _apply_drift_response(self):
-        for op in self.pipeline.ops:
-            if op.on_drift is not None:
-                self.states[op.name] = op.on_drift(self.states[op.name])
+        with span("drift_response"):
+            for op in self.pipeline.ops:
+                if op.on_drift is not None:
+                    self.states[op.name] = op.on_drift(self.states[op.name])
 
     def _collect_op_metrics(self) -> Optional[dict]:
         out: Dict[str, float] = {}
@@ -285,9 +287,10 @@ class Orchestrator:
             self._ckpt_dir = tempfile.mkdtemp(
                 prefix=f"s2ce-{self.job.name}-elastic-")
         axes = elastic.replicated_axes(self.states)
-        self.states, mesh = elastic.rescale_cycle(
-            self._ckpt_dir, step, self.states, axes, {}, plan.workers,
-            meta={"reason": plan.reason, "job": self.job.name}, keep=2)
+        with span("control.rescale", step=step):
+            self.states, mesh = elastic.rescale_cycle(
+                self._ckpt_dir, step, self.states, axes, {}, plan.workers,
+                meta={"reason": plan.reason, "job": self.job.name}, keep=2)
         self.metrics.decisions.append(
             f"{step}:elastic-{plan.action} workers={plan.workers} "
             f"mesh={tuple(mesh.devices.shape)} ({plan.reason})")
@@ -312,13 +315,14 @@ class Orchestrator:
         no directory (or no events) this is a strict no-op — the
         zero-event trajectory stays bitwise identical to a static spec.
         Returns the events handled."""
-        if self._topo_sub is None:
-            return []
-        self.membership.tick(step)
-        events = self._topo_sub.poll()
-        for ev in events:
-            self._apply_topology_event(step, ev, offered)
-        return events
+        with span("control.topology", step=step):
+            if self._topo_sub is None:
+                return []
+            self.membership.tick(step)
+            events = self._topo_sub.poll()
+            for ev in events:
+                self._apply_topology_event(step, ev, offered)
+            return events
 
     def _apply_topology_event(self, step: int, ev, offered: float) -> None:
         from repro.core import membership as ms
@@ -431,69 +435,78 @@ class Orchestrator:
                       record_outputs: bool = False) -> float:
         """Execute one batch under the plan in force; record metrics and
         feed the SLA tracker. Returns the measured event rate."""
-        t0 = time.perf_counter()
-        bd = {k: jnp.asarray(v) for k, v in batch.data.items()}
-        # a fresh per-step key: pipelines with no rng-threading op used
-        # to see the SAME key every batch (stale-RNG bug); splitting
-        # here makes randomness advance regardless of the op set
-        bd["rng"] = jax.random.fold_in(self._root_rng, step)
-        if self.is_graph:
-            self.states, out = self.pipeline.run(self.states, bd,
-                                                 self.frontier,
-                                                 uplink=self._uplink)
-        else:
-            self.states, out = self.pipeline.run(self.states, bd,
-                                                 self.cut,
-                                                 uplink=self._uplink)
-        self.metrics.cuts.append(self.cut)
-        self.metrics.assignments.append(self.frontier)
-        self.metrics.codecs.append(self.codec.name)
-        self.metrics.plan_identities.append(
-            (tuple(sorted(self._exec_assignment.items())),
-             self.codec.name))
-        if record_outputs:
-            self.metrics.outputs.append(
-                {k: np.asarray(v) for k, v in out.items() if k != "rng"})
-        if "drifted" in out and bool(out["drifted"]):
-            self.metrics.drift_alarms += 1
-            self._apply_drift_response()
-        dt = time.perf_counter() - t0
-        rate = batch.n / max(dt, 1e-9)
-        self.sla.observe(dt, rate)
-        self.metrics.events += batch.n
-        return rate
+        with span("execute_batch", step=step, events=batch.n):
+            t0 = time.perf_counter()
+            with span("stage_batch"):
+                bd = {k: jnp.asarray(v) for k, v in batch.data.items()}
+                # a fresh per-step key: pipelines with no rng-threading op
+                # used to see the SAME key every batch (stale-RNG bug);
+                # splitting here makes randomness advance regardless of the
+                # op set
+                bd["rng"] = jax.random.fold_in(self._root_rng, step)
+            if self.is_graph:
+                self.states, out = self.pipeline.run(self.states, bd,
+                                                     self.frontier,
+                                                     uplink=self._uplink)
+            else:
+                self.states, out = self.pipeline.run(self.states, bd,
+                                                     self.cut,
+                                                     uplink=self._uplink)
+            self.metrics.cuts.append(self.cut)
+            self.metrics.assignments.append(self.frontier)
+            self.metrics.codecs.append(self.codec.name)
+            self.metrics.plan_identities.append(
+                (tuple(sorted(self._exec_assignment.items())),
+                 self.codec.name))
+            if record_outputs:
+                self.metrics.outputs.append(
+                    {k: np.asarray(v) for k, v in out.items() if k != "rng"})
+            if "drifted" in out:
+                with span("drift_check"):
+                    drifted = bool(out["drifted"])
+                if drifted:
+                    self.metrics.drift_alarms += 1
+                    self._apply_drift_response()
+            dt = time.perf_counter() - t0
+            rate = batch.n / max(dt, 1e-9)
+            with span("sla_observe"):
+                self.sla.observe(dt, rate)
+            self.metrics.events += batch.n
+            return rate
 
     def apply_decision(self, step: int, d) -> None:
         """Apply an OffloadDecision to the executing partition: codec
         migration and/or re-partition. Hold decisions are no-ops beyond
         the decision log."""
-        if d.reason != "hold":
-            self.metrics.decisions.append(
-                f"{step}:{d.reason} cut={d.cut}")
-        if self._pinned:
-            return
-        if d.codec != self.codec.name:
-            # codec migration: new wire round-trip, flushed EF
-            # residuals (frontier may or may not move with it)
-            self._swap_codec(d.codec, step)
-        if d.frontier != self.frontier:
-            # migration: re-partition — the next pipeline.run
-            # re-fuses segments for the new cut (compile cache
-            # makes revisits free)
-            self.metrics.decisions.append(
-                f"{step}:repartition {self.cut}->{d.cut} "
-                f"edge={sorted(d.frontier)}")
-            self.frontier = d.frontier
-            self.cut = len(d.frontier)
-        self._exec_assignment = dict(d.assignment)
+        with span("control.apply", step=step):
+            if d.reason != "hold":
+                self.metrics.decisions.append(
+                    f"{step}:{d.reason} cut={d.cut}")
+            if self._pinned:
+                return
+            if d.codec != self.codec.name:
+                # codec migration: new wire round-trip, flushed EF
+                # residuals (frontier may or may not move with it)
+                self._swap_codec(d.codec, step)
+            if d.frontier != self.frontier:
+                # migration: re-partition — the next pipeline.run
+                # re-fuses segments for the new cut (compile cache
+                # makes revisits free)
+                self.metrics.decisions.append(
+                    f"{step}:repartition {self.cut}->{d.cut} "
+                    f"edge={sorted(d.frontier)}")
+                self.frontier = d.frontier
+                self.cut = len(d.frontier)
+            self._exec_assignment = dict(d.assignment)
 
     def elastic_step(self, step: int, offered: float, rate: float) -> None:
         """Elastic cloud-pool sizing: grow/shrink the worker count when
         the offered rate persistently over/under-runs the pool; a
         changed plan is DRIVEN through the checkpoint rescale cycle."""
-        plan = self.elastic.observe(step, offered, rate)
-        if plan.changed:
-            self._apply_rescale(step, plan)
+        with span("control.elastic", step=step):
+            plan = self.elastic.observe(step, offered, rate)
+            if plan.changed:
+                self._apply_rescale(step, plan)
 
     def finish(self) -> JobMetrics:
         """Derive the executed-migration count and final telemetry."""

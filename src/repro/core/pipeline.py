@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.costmodel import OperatorCost
+from repro.core.spans import span
 from repro.ml import metrics as mmetrics
 from repro.ml import online
 from repro.streams import drift as drift_mod
@@ -63,6 +64,15 @@ StepFn = Callable[[Any, Batch], Tuple[Any, Batch]]
 
 def _no_state():
     return ()
+
+
+def _named(fn: StepFn, name: str) -> StepFn:
+    """``fn`` under the function name ``name``, which jit gives its
+    program; what it computes is unchanged."""
+    def step(state, batch):
+        return fn(state, batch)
+    step.__name__ = step.__qualname__ = name
+    return step
 
 
 @dataclass(frozen=True)
@@ -377,13 +387,14 @@ class OpGraph:
     def _op_fn(self, i: int) -> Callable:
         """The per-op compiled step — shared by every segment that contains
         op ``i``, which is what makes frontier migration bitwise-safe. One
-        jit wrapper per op; jax itself specializes per batch signature.
-        Host ops (``jit=False``) run their fn directly — they own their
-        compiled executables."""
+        jit wrapper per op, named after the op (its program is
+        ``jit_<op name>`` in a trace); jax itself specializes per batch
+        signature. Host ops (``jit=False``) run their fn directly — they
+        own their compiled executables."""
         fn = self._op_fns.get(i)
         if fn is None:
             op = self.ops[i]
-            fn = jax.jit(op.fn) if op.jit else op.fn
+            fn = jax.jit(_named(op.fn, op.name)) if op.jit else op.fn
             self._op_fns[i] = fn
         return fn
 
@@ -426,7 +437,8 @@ class OpGraph:
         def segment(states: Dict[str, Any], env: Batch):
             states = dict(states)
             for i in idxs:
-                states, env = self._apply(i, states, env)
+                with span("op." + self.ops[i].name):
+                    states, env = self._apply(i, states, env)
             return states, env
 
         return segment
@@ -468,7 +480,8 @@ class OpGraph:
                 # the batch crosses the edge<->cloud wire (uplink, or —
                 # for downlink-ok consumers — the cloud->edge downlink):
                 # apply the link codec's round-trip.
-                batch = uplink(batch)
+                with span("uplink"):
+                    batch = uplink(batch)
             prev_side = side
             sub = {self.ops[i].name: states[self.ops[i].name] for i in idxs}
             fn = self._segment_fn(tuple(idxs), batch)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core.spans import span
 from repro.models import model_zoo as zoo
 from repro.serve.sampling import SamplingParams, sample
 
@@ -90,23 +91,27 @@ class ServeEngine:
             batch["frames"] = jnp.zeros((B, S, cfg.frontend_dim), jnp.float32)
 
         t0 = self._clock()
-        logits, caches = self._prefill(self.params, batch)
-        self.rng, sub = jax.random.split(self.rng)
-        tok = sample(logits[:, 0, :cfg.vocab_size], sub, self.sampling)
+        with span("serve.prefill", rows=B, len=S):
+            logits, caches = self._prefill(self.params, batch)
+            self.rng, sub = jax.random.split(self.rng)
+            tok = sample(logits[:, 0, :cfg.vocab_size], sub, self.sampling)
         jax.block_until_ready(tok)
         self.metrics["prefill_s"] += self._clock() - t0
         self.metrics["prefill_tokens"] += B * S
-        for i, r in enumerate(wave):
-            r.out_tokens.append(int(tok[i]))
+        with span("serve.gather"):
+            for i, r in enumerate(wave):
+                r.out_tokens.append(int(tok[i]))
 
         steps = max(r.max_new_tokens for r in wave) - 1
         t1 = self._clock()
-        for _ in range(steps):
-            tok, caches, self.rng = self._decode(
-                self.params, caches, tok[:, None], self.rng)
-            for i, r in enumerate(wave):
-                if len(r.out_tokens) < r.max_new_tokens:
-                    r.out_tokens.append(int(tok[i]))
+        for step in range(steps):
+            with span("serve.decode_step", i=step):
+                tok, caches, self.rng = self._decode(
+                    self.params, caches, tok[:, None], self.rng)
+            with span("serve.gather"):
+                for i, r in enumerate(wave):
+                    if len(r.out_tokens) < r.max_new_tokens:
+                        r.out_tokens.append(int(tok[i]))
         jax.block_until_ready(tok)
         self.metrics["decode_s"] += self._clock() - t1
         self.metrics["decode_tokens"] += B * steps

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.costmodel import OperatorCost
 from repro.core.pipeline import Op, OpGraph
+from repro.core.spans import span
 from repro.launch.roofline import dl_operator_cost
 from repro.models import model_zoo as zoo
 from repro.serve.engine import ServeEngine
@@ -78,9 +79,12 @@ def prefill_op(engine: ServeEngine, *, prompt_len: int,
     def fn(state, batch):
         model_in = {"tokens": batch["tokens"],
                     **{k: batch[k] for k in extras}}
-        logits, caches = engine._prefill(engine.params, model_in)
-        rng, sub = jax.random.split(batch["rng"])
-        tok = sample(logits[:, 0, :cfg.vocab_size], sub, engine.sampling)
+        rows, length = batch["tokens"].shape
+        with span("serve.prefill", rows=rows, len=length):
+            logits, caches = engine._prefill(engine.params, model_in)
+            rng, sub = jax.random.split(batch["rng"])
+            tok = sample(logits[:, 0, :cfg.vocab_size], sub,
+                         engine.sampling)
         return state, {"kv": caches, "tok": tok, "rng": rng}
 
     if cost is None:
@@ -114,11 +118,13 @@ def decode_op(engine: ServeEngine, *, max_new_tokens: int,
     def fn(state, batch):
         caches, tok, rng = batch["kv"], batch["tok"], batch["rng"]
         toks = [tok]
-        for _ in range(steps):
-            tok, caches, rng = engine._decode(
-                engine.params, caches, tok[:, None], rng)
+        for i in range(steps):
+            with span("serve.decode_step", i=i):
+                tok, caches, rng = engine._decode(
+                    engine.params, caches, tok[:, None], rng)
             toks.append(tok)
-        out = jnp.stack(toks, axis=1).astype(jnp.int32)
+        with span("serve.gather"):
+            out = jnp.stack(toks, axis=1).astype(jnp.int32)
         return state, {"out_tokens": out, "rng": rng}
 
     if cost is None:
