@@ -147,6 +147,103 @@ def test_reservoir_uniformity():
     assert abs(vals.mean() - 1023.5) < 200
 
 
+def _algorithm_r(state, x, y):
+    """The oracle: Algorithm R one event at a time, the whole buffer in the
+    scan's carry."""
+    k = state.buf.shape[0]
+
+    def step(st, item):
+        xi, yi = item
+        rng, r1 = jax.random.split(st.rng)
+        seen = st.seen + 1
+        # position: if seen <= k -> seen-1 else random j in [0, seen)
+        j = jax.random.randint(r1, (), 0, seen)
+        idx = jnp.where(seen <= k, seen - 1, j)
+        take = (seen <= k) | (j < k)
+        idx = jnp.clip(idx, 0, k - 1)
+        buf = jnp.where(take, st.buf.at[idx].set(xi), st.buf)
+        extra = jnp.where(take, st.extra.at[idx].set(yi), st.extra)
+        return samp.ReservoirState(buf, extra, seen, rng), None
+
+    state, _ = jax.lax.scan(step, state, (x, y.astype(jnp.int32)))
+    return state
+
+
+def _assert_states_equal(a, b):
+    for name, u, v in zip(samp.ReservoirState._fields, a, b):
+        np.testing.assert_array_equal(np.asarray(u), np.asarray(v),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("k,n,dim,batches", [
+    (256, 16384, 10, 3),      # the benchmark's fan-out job
+    (64, 100, 3, 3),          # seen crosses k inside the first batch
+    (64, 20, 3, 5),           # batches smaller than k
+    (4, 1, 2, 8),             # one event at a time
+    (4, 4096, 3, 3),          # many events draw each slot
+], ids=["cell", "crosses_k", "under_k", "one_event", "collisions"])
+def test_reservoir_update_is_algorithm_r(k, n, dim, batches):
+    rng = np.random.default_rng(k * 7919 + n)
+    new = ref = samp.reservoir_init(k, dim, seed=5)
+    update, oracle = jax.jit(samp.reservoir_update), jax.jit(_algorithm_r)
+    for _ in range(batches):
+        x = jnp.asarray(rng.normal(size=(n, dim)).astype(np.float32))
+        y = jnp.asarray(rng.integers(0, 2, n).astype(np.int32))
+        new, ref = update(new, x, y), oracle(ref, x, y)
+        _assert_states_equal(new, ref)
+    assert int(new.seen) == n * batches
+
+
+def _loop_carries(jaxpr):
+    """(primitive, carry avals) of every scan and while loop, nested ones
+    included."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    out = []
+    for eqn in jaxpr.eqns:
+        p = eqn.params
+        if eqn.primitive.name == "scan":
+            c0 = p["num_consts"]
+            out.append(("scan", p["jaxpr"].in_avals[c0:c0 + p["num_carry"]]))
+        elif eqn.primitive.name == "while":
+            out.append(("while",
+                        p["body_jaxpr"].in_avals[p["body_nconsts"]:]))
+        for v in p.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    out += _loop_carries(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    out += _loop_carries(sub)
+    return out
+
+
+def test_reservoir_loop_carries_only_the_key():
+    """The buffer stays out of the per-event loop: the one loop there is
+    carries the PRNG key and nothing else."""
+    st = samp.reservoir_init(256, 10)
+    x = jnp.zeros((16384, 10), jnp.float32)
+    y = jnp.zeros((16384,), jnp.int32)
+    loops = _loop_carries(jax.make_jaxpr(samp.reservoir_update)(st, x, y)
+                          .jaxpr)
+    assert [(kind, [(a.shape, a.dtype) for a in carry])
+            for kind, carry in loops] == [
+        ("scan", [(st.rng.shape, st.rng.dtype)])]
+
+
+def test_stratified_update_unchanged(monkeypatch):
+    """Per-class reservoirs, fed one event at a time, end as they did with
+    the per-event Algorithm R scan."""
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(40, 3)).astype(np.float32))
+    y = jnp.asarray(rng.integers(0, 2, 40).astype(np.int32))
+    sr = samp.stratified_init(2, 4, 3, seed=11)
+    new = samp.stratified_update(sr, x, y, 2)
+    monkeypatch.setattr(samp, "reservoir_update", _algorithm_r)
+    ref = samp.stratified_update(sr, x, y, 2)
+    _assert_states_equal(new.states, ref.states)
+    np.testing.assert_array_equal(np.asarray(new.states.seen),
+                                  [int((y == c).sum()) for c in range(2)])
+
+
 def test_misra_gries_finds_heavy_hitter():
     rng = np.random.default_rng(0)
     ids = np.where(rng.random(2000) < 0.3, 7, rng.integers(100, 10_000, 2000))
