@@ -56,10 +56,33 @@ class MoEConfig:
     num_shared: int = 0           # shared (always-on) experts
     layer_period: int = 1         # MoE every `period` layers (1 = all)
     first_dense: int = 0          # leading dense layers before MoE starts
-    capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_coef: float = 0.01
     d_ff_shared: int = 0          # shared-expert hidden (default = d_ff_expert * num_shared)
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum to one
+    routed_scaling_factor: float = 1.0   # routed experts' output multiplier
+    # the contiguous range of routed experts this layer holds (expert
+    # parallelism: a chip holds its share); 0 held => all of them
+    first_held: int = 0
+    num_held: int = 0
+
+    @property
+    def held(self) -> tuple:
+        """``(first, stop)`` of the routed experts this layer computes."""
+        n = self.num_held or self.num_experts
+        return self.first_held, self.first_held + n
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 states it:
+    ``rope_scaling`` of the model's config.json."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -70,6 +93,7 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    rope_scaling: Optional[YaRNConfig] = None
 
 
 @dataclass(frozen=True)
@@ -274,10 +298,15 @@ class ArchConfig:
             active += layer_attn
             if i < len(moe_mask) and moe_mask[i] and self.moe.num_experts:
                 e = self.moe
+                lo, hi = e.held
                 per_expert = mlp_params(e.d_ff_expert)
-                shared = e.num_shared * mlp_params(e.d_ff_shared or e.d_ff_expert)
-                total += e.num_experts * per_expert + shared
-                active += e.top_k * per_expert + shared
+                # the shared experts act as one MLP, as moe_specs lays them out
+                shared = mlp_params(e.d_ff_shared or e.num_shared * e.d_ff_expert) \
+                    if e.num_shared else 0
+                total += (hi - lo) * per_expert + shared
+                # a token's top-k land on the held share at its size's rate
+                active += e.top_k * per_expert * (hi - lo) // e.num_experts \
+                    + shared
             else:
                 total += mlp_params(ff)
                 active += mlp_params(ff)

@@ -2,12 +2,19 @@
 
 [arXiv:2405.04434; hf]  27L, d_model=2048, 16H, MLA kv_lora=512,
 rope/nope head dims 64/128; layer 0 dense (d_ff=10944), layers 1..26 MoE
-with 64 routed experts (d_ff=1408, top-6) + 2 shared experts.
+with 64 routed experts (d_ff=1408, top-6, raw softmax gates:
+``norm_topk_prob`` false, ``routed_scaling_factor`` 1) + 2 shared experts;
+YaRN rope scaling (factor 40 over 4,096 positions, mscale 0.707 on both
+sides) and RMSNorm epsilon 1e-6, as the model's config.json states them.
 MLA is full attention -> long_500k skipped; its compressed KV cache is a
 first-class serving feature (kv cache = kv_lora + rope dims per token).
 """
 
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, register
+from repro.configs.base import (
+    ArchConfig, MLAConfig, MoEConfig, YaRNConfig, register)
+
+YARN = YaRNConfig(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                  beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707)
 
 FULL = ArchConfig(
     name="deepseek-v2-lite-16b",
@@ -20,11 +27,14 @@ FULL = ArchConfig(
     d_head=128,
     d_ff=10944,               # dense layers (layer 0)
     vocab_size=102400,
+    norm_eps=1e-6,
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=0,
-                  rope_head_dim=64, nope_head_dim=128, v_head_dim=128),
+                  rope_head_dim=64, nope_head_dim=128, v_head_dim=128,
+                  rope_scaling=YARN),
     moe=MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
                   num_shared=2, d_ff_shared=2816,
-                  layer_period=1, first_dense=1, capacity_factor=1.25),
+                  layer_period=1, first_dense=1, norm_topk_prob=False,
+                  routed_scaling_factor=1.0),
     recipe="ep_fsdp",
     remat="full",
     microbatches=1,
@@ -41,11 +51,14 @@ SMOKE = ArchConfig(
     d_ff=160,
     vocab_size=512,
     vocab_pad_multiple=16,
+    norm_eps=1e-6,
     mla=MLAConfig(kv_lora_rank=32, q_lora_rank=0,
-                  rope_head_dim=8, nope_head_dim=16, v_head_dim=16),
+                  rope_head_dim=8, nope_head_dim=16, v_head_dim=16,
+                  rope_scaling=YARN),
     moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=48,
                   num_shared=1, d_ff_shared=48,
-                  layer_period=1, first_dense=1, capacity_factor=2.0),
+                  layer_period=1, first_dense=1, norm_topk_prob=False,
+                  routed_scaling_factor=1.0),
     param_dtype="float32",
     compute_dtype="float32",
     recipe="dp",

@@ -22,7 +22,7 @@ FULL = ArchConfig(
     mlp_act="silu_glu",
     tie_embeddings=True,
     moe=MoEConfig(num_experts=32, top_k=8, d_ff_expert=512,
-                  layer_period=1, capacity_factor=1.25),
+                  layer_period=1),
     recipe="ep_fsdp",
     remat="full",
     microbatches=1,
@@ -41,7 +41,7 @@ SMOKE = ArchConfig(
     vocab_pad_multiple=16,
     tie_embeddings=True,
     moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=64,
-                  layer_period=1, capacity_factor=2.0),
+                  layer_period=1),
     param_dtype="float32",
     compute_dtype="float32",
     recipe="dp",

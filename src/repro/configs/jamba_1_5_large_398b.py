@@ -24,7 +24,7 @@ FULL = ArchConfig(
     attn_period=8,
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2, chunk=256),
     moe=MoEConfig(num_experts=16, top_k=2, d_ff_expert=24576,
-                  layer_period=2, capacity_factor=1.25),
+                  layer_period=2),
     recipe="ep_tp_fsdp",
     remat="full",
     microbatches=8,
@@ -47,7 +47,7 @@ SMOKE = ArchConfig(
     attn_period=4,
     mamba=MambaConfig(d_state=4, d_conv=4, expand=2, chunk=16),
     moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=128,
-                  layer_period=2, capacity_factor=2.0),
+                  layer_period=2),
     param_dtype="float32",
     compute_dtype="float32",
     recipe="dp",

@@ -6,6 +6,20 @@ no profiler recording it costs about a microsecond; while one records
 the device's programs, with its keyword arguments as stats. There is no
 switch: tracing is on exactly while a profiler runs. Spans of one batch
 or wave carry ``step``, or sit inside a span that does.
+
+Inside the jitted model programs, ``jax.named_scope`` names the device
+work of the latent attention and expert layers in each op's HLO metadata
+(``op_name``), which a trace viewer shows beside the op; they cost
+nothing at run time:
+
+- ``s2ce.mla.decode_latent``: a decode step's latent attention, scored
+  against the latent cache (``models/attention.py``);
+- ``s2ce.mla.prefill``: latent attention expanded into per-head keys and
+  values (prefill and full-sequence passes);
+- ``s2ce.moe.route``: the router, its top-k and the balance loss
+  (``models/moe.py``);
+- ``s2ce.moe.experts``: the held experts (dense for a few tokens, else
+  grouped products) and the shared experts.
 """
 
 import jax

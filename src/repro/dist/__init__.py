@@ -37,7 +37,6 @@ Logical-axis naming conventions (used across ``models/transformer.py``,
   dinner         SSM/RWKV inner feature dim (TP over model)
   vocab          softmax/vocab dim (TP over model)
   experts        expert weight dim (expert parallel over model)
-  expert_groups  MoE token-group dim G (mirrors data sharding)
   layers         scanned layer stack dim (always replicated)
   head_dim/lora  per-head / low-rank dims (always replicated)
   ============== =====================================================

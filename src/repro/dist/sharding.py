@@ -9,8 +9,7 @@ expands it into two rule tables consumed by :func:`repro.dist.api
     ``ff`` / ``vocab`` over ``model``; EP shards ``experts`` over
     ``model``).
   * ``rules["act"]``  — how activation dims map (``batch`` over the
-    data axes, TP-parallel dims over ``model``, MoE token groups over
-    ``expert_groups`` -> data).
+    data axes, TP-parallel dims over ``model``).
 
 Rules reference the *union* mesh axes (``pod``, ``data``, ``model``);
 axes absent from the actual mesh are dropped at spec time, so the same
@@ -61,7 +60,7 @@ def build_rules(cfg=None, *, shape=None, recipe: Optional[str] = None) -> dict:
         if not ep:
             param["experts"] = _MODEL
 
-    act = {"batch": _DATA, "expert_groups": _DATA}
+    act = {"batch": _DATA}
     if ep:
         act["experts"] = _MODEL
     if tp:

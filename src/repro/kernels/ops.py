@@ -38,6 +38,34 @@ def pallas_available() -> bool:
     return jax.default_backend() == "tpu" or _interpret()
 
 
+def gmm_tiling(k: int, n: int) -> tuple:
+    """Row, contraction and output tiles of :func:`grouped_matmul`: the
+    contraction whole up to 2,048 (one accumulation, as the XLA path
+    does), output tiles of at most 1,408 columns. At DeepSeek-V2-Lite's
+    expert shapes these were the fastest of those that fit the v5e's
+    VMEM (measured on the chip, ``PERF.md``)."""
+    if n <= 1408:
+        return 256, min(k, 2048), n
+    return 512, min(k, 2048), 1024
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` (m, k) rows in consecutive groups, group ``g`` holding
+    ``group_sizes[g]`` rows, each times ``rhs[g]`` (k, n): the grouped
+    product of a sorted expert layer. Rows past the last group are left
+    undefined. On a TPU (or in interpret mode) the megablox Pallas kernel,
+    which visits only the row tiles the groups cover; otherwise
+    ``jax.lax.ragged_dot``."""
+    if not pallas_available():
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = lhs.shape
+    tiling = gmm_tiling(k, rhs.shape[2])
+    lhs = jnp.pad(lhs, ((0, -m % tiling[0]), (0, 0)))
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False,
+               _interpret())[:m]
+
+
 def flash_supported(q, k, v, causal, q_offset, kv_len) -> bool:
     """Kernel handles plain causal/full attention without offsets/lengths
     (the cached-decode path uses the XLA implementation)."""

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.dist import axis_size, shard
-from repro.models.layers import apply_norm, apply_rope
+from repro.models.layers import apply_rope, yarn_mscale
 from repro.models.params import Spec
 
 NEG_INF = -1e30
@@ -100,12 +100,14 @@ def _group(q: jax.Array, n_kv: int):
 
 
 def dense_attention(q, k, v, *, causal: bool, q_offset=0,
-                    kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """Materialized-scores attention. q:(B,S,H,Dh) k,v:(B,T,KV,Dh)."""
+                    kv_len: Optional[jax.Array] = None,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Materialized-scores attention. q:(B,S,H,Dh) k,v:(B,T,KV,Dh).
+    ``scale`` defaults to Dh ** -0.5."""
     B, S, H, Dh = q.shape
     T, KV = k.shape[1], k.shape[2]
     qg = _group(q, KV)
-    scale = 1.0 / math.sqrt(Dh)
+    scale = 1.0 / math.sqrt(Dh) if scale is None else scale
     s = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     mask = _make_mask(S, T, causal, q_offset, kv_len, B)
@@ -132,7 +134,8 @@ def _make_mask(S, T, causal, q_offset, kv_len, B):
 
 
 def chunked_attention(q, k, v, *, causal: bool, chunk: int = 512, q_offset=0,
-                      kv_len: Optional[jax.Array] = None) -> jax.Array:
+                      kv_len: Optional[jax.Array] = None,
+                      scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention scanning KV blocks; O(S*chunk) memory."""
     B, S, H, Dh = q.shape
     T, KV = k.shape[1], k.shape[2]
@@ -146,7 +149,7 @@ def chunked_attention(q, k, v, *, causal: bool, chunk: int = 512, q_offset=0,
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
     qg = _group(q, KV).astype(jnp.float32)
-    scale = 1.0 / math.sqrt(Dh)
+    scale = 1.0 / math.sqrt(Dh) if scale is None else scale
     ks = jnp.moveaxis(k.reshape(B, nblk, chunk, KV, k.shape[-1]), 1, 0)
     vs = jnp.moveaxis(v.reshape(B, nblk, chunk, KV, Dv), 1, 0)
 
@@ -189,17 +192,19 @@ def chunked_attention(q, k, v, *, causal: bool, chunk: int = 512, q_offset=0,
 
 
 def attention(q, k, v, *, causal: bool, impl: str = "dense", chunk: int = 512,
-              q_offset=0, kv_len=None) -> jax.Array:
+              q_offset=0, kv_len=None, scale=None) -> jax.Array:
     if impl == "pallas":
         from repro.kernels import ops as kops
-        if kops.flash_supported(q, k, v, causal, q_offset, kv_len):
+        if scale is None and kops.flash_supported(q, k, v, causal, q_offset,
+                                                  kv_len):
             return kops.flash_attention(q, k, v, causal=causal)
         impl = "chunked"
     if impl == "chunked" and k.shape[1] > chunk:
         return chunked_attention(q, k, v, causal=causal, chunk=chunk,
-                                 q_offset=q_offset, kv_len=kv_len)
+                                 q_offset=q_offset, kv_len=kv_len,
+                                 scale=scale)
     return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
-                           kv_len=kv_len)
+                           kv_len=kv_len, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -275,12 +280,34 @@ class MLACache(NamedTuple):
     length: jax.Array
 
 
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """(nope + rope head dims) ** -0.5, times YaRN's ``mscale_all_dim``
+    factor squared where the rope is scaled (DeepSeek-V2's attention)."""
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    y = m.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
                   cache: Optional[MLACache] = None, impl: str = "chunked"):
+    """Latent attention. A decode step (one new position against a cache)
+    scores in the latent space; every other call expands the latent into
+    per-head keys and values."""
+    decode = cache is not None and x.shape[1] == 1
+    with jax.named_scope("s2ce.mla.decode_latent" if decode
+                         else "s2ce.mla.prefill"):
+        return _mla(p, cfg, x, positions=positions, cache=cache, impl=impl,
+                    decode=decode)
+
+
+def _mla(p, cfg: ArchConfig, x, *, positions, cache, impl, decode):
     m = cfg.mla
     B, S, d = x.shape
     H = cfg.n_heads
-    dn, dr, dv = m.nope_head_dim, m.rope_head_dim, m.v_head_dim
+    dn, dr = m.nope_head_dim, m.rope_head_dim
 
     if m.q_lora_rank:
         cq = x @ p["w_dq"]
@@ -290,15 +317,17 @@ def mla_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
     else:
         q = jnp.einsum("bsd,dhe->bshe", x, p["wq"].astype(x.dtype))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, m.rope_scaling)
 
     c = x @ p["w_dkv"]                                   # (B,S,r)
     cf = c.astype(jnp.float32)
     c = (cf * jax.lax.rsqrt(jnp.mean(jnp.square(cf), -1, keepdims=True)
                             + cfg.norm_eps) * p["kv_norm"].astype(jnp.float32)
          ).astype(x.dtype)
-    kr = apply_rope((x @ p["w_kr"])[:, :, None, :], positions, cfg.rope_theta)
+    kr = apply_rope((x @ p["w_kr"])[:, :, None, :], positions, cfg.rope_theta,
+                    m.rope_scaling)
     kr = kr[:, :, 0, :]                                  # (B,S,dr)
+    scale = mla_softmax_scale(cfg)
 
     q_offset = 0
     kv_len = None
@@ -312,8 +341,25 @@ def mla_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
     else:
         new_cache = None
 
-    # expand latent -> per-head keys/values (naive path; absorbed variant is a
-    # perf iteration, see EXPERIMENTS.md §Perf)
+    if decode:
+        # the query absorbs W_uk, the context leaves through W_uv: scores
+        # and the weighted sum run against the latent cache itself, and no
+        # per-head key or value is formed from it
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bshe,rhe->bshr", q_nope.astype(f32),
+                           p["w_uk"].astype(f32))
+        s = (jnp.einsum("bshr,btr->bhst", q_lat, c.astype(f32))
+             + jnp.einsum("bshe,bte->bhst", q_rope.astype(f32),
+                          kr.astype(f32))) * scale
+        valid = jnp.arange(c.shape[1]) < kv_len
+        s = jnp.where(valid, s, NEG_INF)
+        ctx = jnp.einsum("bhst,btr->bshr", jax.nn.softmax(s, axis=-1),
+                         c.astype(f32))
+        o = jnp.einsum("bshr,rhe->bshe", ctx.astype(x.dtype),
+                       p["w_uv"].astype(x.dtype))
+        return jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype)), \
+            new_cache
+
     k_nope = jnp.einsum("btr,rhe->bthe", c, p["w_uk"].astype(x.dtype))
     vv = jnp.einsum("btr,rhe->bthe", c, p["w_uv"].astype(x.dtype))
     T = k_nope.shape[1]
@@ -324,7 +370,7 @@ def mla_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
     vv = shard(vv, "batch", "kv_seq", "heads", None)
 
     o = attention(qq, k, vv, causal=True, impl=impl, chunk=cfg.attn_chunk,
-                  q_offset=q_offset, kv_len=kv_len)
+                  q_offset=q_offset, kv_len=kv_len, scale=scale)
     out = jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype))
     return out, new_cache
 

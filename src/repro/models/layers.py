@@ -7,6 +7,7 @@ logical axes via :func:`repro.dist.shard`.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -92,15 +93,50 @@ def rope_freqs(d_head: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, d_head, 2, dtype=np.float32) / d_head))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, Dh); positions: (B, S) or (S,)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``yarn_get_mscale`` of DeepSeek-V2's modeling code."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(d_head: int, theta: float, y) -> np.ndarray:
+    """``DeepseekV2YarnRotaryEmbedding``'s inverse frequencies: the plain
+    ones below the correction range, divided by ``y.factor`` above it, and
+    a linear ramp between, at every position."""
+    idx = np.arange(0, d_head, 2, dtype=np.float32) / d_head
+    extra = 1.0 / (theta ** idx)
+    inter = 1.0 / (y.factor * theta ** idx)
+
+    def dim_of(rotations):
+        return (d_head * math.log(y.original_max_position
+                                  / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(y.beta_fast)), 0)
+    high = min(math.ceil(dim_of(y.beta_slow)), d_head - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d_head // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1).astype(np.float32)
+    return inter * ramp + extra * (1 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (B, S, H, Dh); positions: (B, S) or (S,). ``scaling``: a
+    :class:`~repro.configs.base.YaRNConfig`, or None for plain rope."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta))              # (d/2,)
+    freqs = jnp.asarray(rope_freqs(d, theta) if scaling is None
+                        else yarn_freqs(d, theta, scaling))  # (d/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,d/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

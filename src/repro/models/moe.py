@@ -1,14 +1,16 @@
-"""Mixture-of-Experts with grouped sort-based capacity dispatch (TPU-native).
+"""Mixture-of-Experts: a dropless expert layer over the experts it holds.
 
-No atomic scatters (TPU has none): token->expert routing is a stable sort
-over expert ids + positional scatter into an (E, C, d) buffer. The dispatch
-is *grouped*: tokens are reshaped to (G, t/G, d) where G matches the data
-sharding, and the sort/scatter runs per group under vmap — every dispatch
-op keeps a sharded leading dim, so GSPMD never replicates token tensors
-(the ungrouped variant materialized unsharded (t*K, d) fp32 tensors; see
-EXPERIMENTS.md §Perf for the before/after). Expert compute shards E over
-the `experts` logical axis (expert parallelism); overflow beyond capacity
-is dropped (GShard/Switch semantics). Shared experts run densely.
+The router keeps all ``num_experts`` outputs (softmax, greedy top-k, gates
+renormalised only where ``norm_topk_prob`` says so, times
+``routed_scaling_factor``). The layer holds the contiguous range
+``MoEConfig.held`` of the routed experts (all of them by default; under
+expert parallelism a chip holds its share) and computes, for every
+assignment routed to a held expert, that expert's gated output: the
+assignments are sorted by expert and run through one grouped product
+(``kernels.ops.grouped_matmul``) per projection, or, for a few tokens,
+every held expert runs densely with the unrouted pairs' gates zero.
+Nothing is dropped and there is no capacity. What experts held elsewhere
+would add is not computed here. Shared experts run densely, once.
 
 Returns the load-balancing auxiliary loss alongside the output.
 """
@@ -21,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.dist import axis_size, shard
+from repro.kernels.ops import grouped_matmul
 from repro.models.layers import _act
 from repro.models.params import Spec
 
@@ -29,14 +31,15 @@ from repro.models.params import Spec
 def moe_specs(cfg: ArchConfig):
     e = cfg.moe
     d, f = cfg.d_model, e.d_ff_expert
+    lo, hi = e.held
     glu = cfg.mlp_act.endswith("_glu")
     sp = {
         "router": Spec((d, e.num_experts), ("embed", "experts"), scale=0.02),
-        "w_up": Spec((e.num_experts, d, f), ("experts", "embed", "ff")),
-        "w_down": Spec((e.num_experts, f, d), ("experts", "ff", "embed")),
+        "w_up": Spec((hi - lo, d, f), ("experts", "embed", "ff")),
+        "w_down": Spec((hi - lo, f, d), ("experts", "ff", "embed")),
     }
     if glu:
-        sp["w_gate"] = Spec((e.num_experts, d, f), ("experts", "embed", "ff"))
+        sp["w_gate"] = Spec((hi - lo, d, f), ("experts", "embed", "ff"))
     if e.num_shared:
         fs = e.d_ff_shared or e.num_shared * f
         sp["shared"] = {
@@ -48,48 +51,70 @@ def moe_specs(cfg: ArchConfig):
     return sp
 
 
-def _capacity(cfg: ArchConfig, n_tokens: int) -> int:
+def route(router, cfg: ArchConfig, xf: jax.Array, rng=None):
+    """xf: (t, D) -> (probs (t, E) fp32, gates (t, K) fp32, ids (t, K))."""
     e = cfg.moe
-    c = int(n_tokens * e.top_k * e.capacity_factor / e.num_experts)
-    return max(8, -(-c // 8) * 8)   # round up to 8
+    logits = (xf @ router.astype(xf.dtype)).astype(jnp.float32)
+    if e.router_jitter and rng is not None:
+        logits = logits + e.router_jitter * jax.random.normal(rng, logits.shape)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, e.top_k)
+    if e.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return probs, gates * e.routed_scaling_factor, ids
 
 
-def _dispatch_group(cfg: ArchConfig, C: int, xf, expert_ids, gate_vals):
-    """Per-group dispatch. xf: (t,D); expert_ids/gate_vals: (t,K).
-    Returns (buf (E,C,D), dest (t*K,), order (t*K,), keep (t*K,))."""
+# at most this many (token, held expert) pairs are computed densely: about
+# a row tile of the grouped product, which would visit a tile for each
+# group the tokens reach
+DENSE_PAIRS = 256
+
+
+def held_experts(p, cfg: ArchConfig, xf: jax.Array, gates, ids) -> jax.Array:
+    """Sum over each token's assignments to the held experts of gate times
+    expert output. xf: (t, D); gates, ids: (t, K) -> (t, D). A few tokens
+    (a decode step) go through every held expert densely, at a cost that
+    does not depend on the routing; more go through the grouped product."""
     e = cfg.moe
+    lo, hi = e.held
+    n = hi - lo
     t, D = xf.shape
-    E, K = e.num_experts, e.top_k
-    flat_e = expert_ids.reshape(-1)                                # (t*K,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    tok_of = order // K
-    counts = jnp.bincount(flat_e, length=E)
-    starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)[:-1]])
-    pos = jnp.arange(t * K) - starts[sorted_e]
-    keep = pos < C
-    dest = jnp.where(keep, sorted_e * C + pos, E * C)              # overflow
-    # scatter ONLY int32 indices (a data scatter would materialize a huge
-    # u32 index broadcast under GSPMD); the payload moves via gather
-    slot_to_assign = jnp.full((E * C + 1,), t * K, jnp.int32).at[dest].set(
-        jnp.arange(t * K, dtype=jnp.int32))
-    slot_tok = jnp.where(slot_to_assign[:-1] < t * K,
-                         tok_of[jnp.minimum(slot_to_assign[:-1], t * K - 1)],
-                         t)                                        # sentinel
-    xf_pad = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])
-    buf = xf_pad[slot_tok]                                         # (E*C, D)
-    return buf.reshape(E, C, D), dest, order, keep
-
-
-def _combine_group(out_buf, dest, order, keep, gate_flat, t, K, D):
-    """out_buf: (E,C,D) -> y (t,D) weighted by gates (all gathers)."""
-    flat_out = jnp.concatenate(
-        [out_buf.reshape(-1, D), jnp.zeros((1, D), out_buf.dtype)])
-    y_sorted = flat_out[dest] * gate_flat[order][:, None]          # (t*K,D)
-    inv = jnp.argsort(order)                                       # assign->sorted pos
-    y_assign = y_sorted[inv]
-    return y_assign.reshape(t, K, D).sum(axis=1)
+    K = ids.shape[-1]
+    dt = xf.dtype
+    local = ids - lo                                               # (t, K)
+    held = (local >= 0) & (local < n)
+    if t * n <= DENSE_PAIRS:
+        gate = jnp.sum(jnp.where(local[..., None] == jnp.arange(n),
+                                 gates[..., None], 0.0), axis=1)   # (t, n)
+        up = jnp.einsum("td,edf->tef", xf, p["w_up"].astype(dt))
+        if "w_gate" in p:
+            h = _act(cfg.mlp_act, jnp.einsum(
+                "td,edf->tef", xf, p["w_gate"].astype(dt))) * up
+        else:
+            h = _act(cfg.mlp_act, up)
+        y = jnp.einsum("tef,efd->ted", h, p["w_down"].astype(dt))
+        return jnp.einsum("ted,te->td", y, gate.astype(dt))
+    # held assignments first, grouped by expert; the rest sort last and
+    # fall outside every group
+    key = jnp.where(held, local, n).reshape(-1)                    # (t*K,)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=n + 1)[:n]
+    in_group = held.reshape(-1)[order]
+    # rows outside every group are left undefined by the grouped product:
+    # zero them going in and select them out coming back, so that nothing
+    # they hold reaches the sum or a gradient
+    xs = jnp.where(in_group[:, None], xf[order // K], 0)           # (t*K, D)
+    up = grouped_matmul(xs, p["w_up"].astype(dt), sizes)
+    if "w_gate" in p:
+        h = _act(cfg.mlp_act, grouped_matmul(
+            xs, p["w_gate"].astype(dt), sizes)) * up
+    else:
+        h = _act(cfg.mlp_act, up)
+    ys = grouped_matmul(h, p["w_down"].astype(dt), sizes)          # (t*K, D)
+    g = gates.reshape(-1)[order].astype(dt)
+    ys = jnp.where(in_group[:, None], ys * g[:, None], 0)
+    # back to assignment order (gathers only), then sum each token's K
+    return ys[jnp.argsort(order)].reshape(t, K, D).sum(axis=1)
 
 
 def apply_moe(p, cfg: ArchConfig, x: jax.Array,
@@ -97,61 +122,22 @@ def apply_moe(p, cfg: ArchConfig, x: jax.Array,
     """x: (B,S,D) -> (out (B,S,D), aux_loss scalar)."""
     e = cfg.moe
     B, S, D = x.shape
-    t = B * S
-    E, K = e.num_experts, e.top_k
-
-    G = max(1, axis_size("expert_groups"))
-    if t % G:
-        G = 1
-    tg = t // G
-    xg = shard(x.reshape(G, tg, D), "expert_groups", None, None)
-
-    logits = (xg @ p["router"].astype(xg.dtype)).astype(jnp.float32)
-    if e.router_jitter and rng is not None:
-        logits = logits + e.router_jitter * jax.random.normal(rng, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)                        # (G,tg,E)
-    gate_vals, expert_ids = jax.lax.top_k(probs, K)                # (G,tg,K)
-    gate_vals = (gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)).astype(xg.dtype)
-
-    # load-balance aux (Switch): E * sum_e f_e * P_e, averaged over groups
-    me = jnp.mean(probs, axis=1)                                   # (G,E)
-    fe = jnp.mean(jax.nn.one_hot(expert_ids[..., 0], E,
-                                 dtype=jnp.float32), axis=1)       # (G,E)
-    aux = e.aux_loss_coef * E * jnp.mean(jnp.sum(fe * me, axis=-1))
-
-    C = _capacity(cfg, tg)
-    buf, dest, order, keep = jax.vmap(
-        lambda xf, ids, gv: _dispatch_group(cfg, C, xf, ids, gv)
-    )(xg, expert_ids, gate_vals)
-    buf = shard(buf, "expert_groups", "experts", None, None)       # (G,E,C,D)
-
-    if "w_gate" in p:
-        h = _act(cfg.mlp_act, jnp.einsum(
-            "gecd,edf->gecf", buf, p["w_gate"].astype(buf.dtype)))
-        h = h * jnp.einsum("gecd,edf->gecf", buf, p["w_up"].astype(buf.dtype))
-    else:
-        h = _act(cfg.mlp_act, jnp.einsum(
-            "gecd,edf->gecf", buf, p["w_up"].astype(buf.dtype)))
-    h = shard(h, "expert_groups", "experts", None, "ff")
-    out_buf = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(buf.dtype))
-    out_buf = shard(out_buf, "expert_groups", "experts", None, None)
-
-    y = jax.vmap(
-        lambda ob, de, orr, ke, gf: _combine_group(ob, de, orr, ke, gf,
-                                                   tg, K, D)
-    )(out_buf, dest, order, keep, gate_vals.reshape(G, tg * K))
-    y = shard(y, "expert_groups", None, None)
-    y = y.reshape(B, S, D)
-
-    if e.num_shared:
-        sp = p["shared"]
-        xf = x.reshape(t, D)
-        if "w_gate" in sp:
-            hs = _act(cfg.mlp_act, xf @ sp["w_gate"].astype(xf.dtype)) * (
-                xf @ sp["w_up"].astype(xf.dtype))
-        else:
-            hs = _act(cfg.mlp_act, xf @ sp["w_up"].astype(xf.dtype))
-        y = y + (hs @ sp["w_down"].astype(xf.dtype)).reshape(B, S, D)
-
-    return y, aux
+    xf = x.reshape(B * S, D)
+    with jax.named_scope("s2ce.moe.route"):
+        probs, gates, ids = route(p["router"], cfg, xf, rng)
+        # load-balance aux (Switch): E * sum_e f_e * P_e
+        fe = jnp.mean(jax.nn.one_hot(ids[:, 0], e.num_experts,
+                                     dtype=jnp.float32), axis=0)
+        aux = e.aux_loss_coef * e.num_experts * jnp.sum(
+            fe * jnp.mean(probs, axis=0))
+    with jax.named_scope("s2ce.moe.experts"):
+        y = held_experts(p, cfg, xf, gates, ids)
+        if e.num_shared:
+            sp = p["shared"]
+            if "w_gate" in sp:
+                hs = _act(cfg.mlp_act, xf @ sp["w_gate"].astype(xf.dtype)) * (
+                    xf @ sp["w_up"].astype(xf.dtype))
+            else:
+                hs = _act(cfg.mlp_act, xf @ sp["w_up"].astype(xf.dtype))
+            y = y + hs @ sp["w_down"].astype(xf.dtype)
+    return y.reshape(B, S, D), aux

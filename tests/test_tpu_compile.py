@@ -19,16 +19,33 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import countmin, ef_codec, flash_attention, mamba_scan
-from repro.kernels import preprocess, rwkv6_wkv
+from repro.kernels import ops, preprocess, rwkv6_wkv
 
 N = 65536             # events per batch
 W = 65536             # count-min width
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
+
+
+def _gmm(k, n):
+    """The grouped product as ``ops.grouped_matmul`` calls it on a TPU."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    return lambda lhs, rhs, sizes: gmm(lhs, rhs, sizes, BF16,
+                                       ops.gmm_tiling(k, n))
+
+
 # name -> (kernel, argument shapes); shapes are the deployment widths the
 # on-chip smoke run uses (qwen2-1.5b for attention; rwkv6-1.6b and a
-# Jamba-style mamba block for the recurrent kernels)
+# Jamba-style mamba block for the recurrent kernels; a DeepSeek-V2-Lite
+# prefill wave's 16 x 1,500 x 6 assignments, padded to the row tile, over
+# 8 held experts for the grouped product)
 CASES = {
+    "grouped_matmul_up": (
+        _gmm(2048, 1408),
+        [((144384, 2048), BF16), ((8, 2048, 1408), BF16), ((8,), I32)]),
+    "grouped_matmul_down": (
+        _gmm(1408, 2048),
+        [((144384, 1408), BF16), ((8, 1408, 2048), BF16), ((8,), I32)]),
     "countmin_update": (
         lambda ids, seeds: countmin.countmin_update(ids, 4, W, seeds),
         [((N,), I32), ((4, 2), I32)]),
