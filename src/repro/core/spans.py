@@ -19,7 +19,13 @@ nothing at run time:
 - ``s2ce.moe.route``: the router, its top-k and the balance loss
   (``models/moe.py``);
 - ``s2ce.moe.experts``: the held experts (dense for a few tokens, else
-  grouped products) and the shared experts.
+  grouped products) and the shared experts;
+- ``s2ce.scan.param_barrier``: the layer scan's barrier on each layer's
+  parameter slice (``models/transformer.py``). It is there only where a
+  mesh of more than one device can all-gather a layer's weights; a
+  program without it reads each layer's weights in place from the stack.
+  The lowered program's locations name it; the compiler may drop the
+  barrier itself from the compiled text.
 """
 
 import jax

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.dist import shard
+from repro.dist import current_mesh, shard
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
@@ -300,6 +300,32 @@ def _diff_barrier_bwd(_, ct):
 
 _diff_barrier.defvjp(_diff_barrier_fwd, _diff_barrier_bwd)
 
+# names the barrier in the lowered program, present exactly where it
+# engages (listed in ``repro.core.spans``)
+PARAM_BARRIER = "s2ce.scan.param_barrier"
+
+
+def _gathers_params() -> bool:
+    """Whether the active mesh can all-gather a layer's weights: a mesh of
+    more than one device."""
+    mesh = current_mesh()
+    return mesh is not None and mesh.size > 1
+
+
+def _layer_params(lp, axes):
+    """One layer's parameter slice as the scan body reads it. Where a mesh
+    of more than one device shards the stack, a barrier plus per-leaf pins
+    keep the slice's FSDP all-gather inside the loop, so XLA cannot hoist
+    a gather of the whole stack out of the scan. Otherwise the slice is
+    returned untouched and the layer reads its weights in place from the
+    stacked operand: behind the barrier XLA copies every layer's slice
+    out of the stack on every pass."""
+    if not _gathers_params():
+        return lp
+    with jax.named_scope(PARAM_BARRIER):
+        lp = _diff_barrier(lp)
+    return _constrain_layer_params(lp, axes)
+
 
 def run_stack(params, cfg: ArchConfig, pattern, x, *, positions, memory,
               caches, impl, stack_axes=None):
@@ -310,11 +336,12 @@ def run_stack(params, cfg: ArchConfig, pattern, x, *, positions, memory,
         aux = jnp.zeros((), jnp.float32)
         new_caches = []
         for i, slot in enumerate(pattern):
-            if i:
+            if i and _gathers_params():
                 # serialize weight-gathers across unrolled slots: slot i+1's
                 # FSDP all-gather must wait for slot i's output, otherwise
                 # every slot's full weights are live simultaneously
-                x, layer_params = _diff_barrier((x, layer_params))
+                with jax.named_scope(PARAM_BARRIER):
+                    x, layer_params = _diff_barrier((x, layer_params))
             c = layer_caches[i] if layer_caches is not None else None
             x, nc, a = apply_slot(layer_params[i], cfg, slot, x,
                                   positions=positions, memory=memory,
@@ -327,21 +354,14 @@ def run_stack(params, cfg: ArchConfig, pattern, x, *, positions, memory,
 
     if caches is None:
         def scan_body(x, lp):
-            # barrier + per-leaf constraints pin the per-layer param slice
-            # inside the loop so XLA cannot hoist FSDP all-gathers of the
-            # whole stack out of the scan
-            lp = _diff_barrier(lp)
-            lp = _constrain_layer_params(lp, stack_axes)
-            x, _, aux = body(x, lp, None)
+            x, _, aux = body(x, _layer_params(lp, stack_axes), None)
             return x, aux
         x, auxs = jax.lax.scan(scan_body, x, params)
         return x, None, jnp.sum(auxs)
 
     def scan_body(x, xs):
         lp, lc = xs
-        lp = _diff_barrier(lp)
-        lp = _constrain_layer_params(lp, stack_axes)
-        x, nc, aux = body(x, lp, lc)
+        x, nc, aux = body(x, _layer_params(lp, stack_axes), lc)
         return x, (nc, aux)
     x, (new_caches, auxs) = jax.lax.scan(scan_body, x, (params, caches))
     return x, new_caches, jnp.sum(auxs)

@@ -126,6 +126,38 @@ def test_programs_name_their_latent_attention_and_expert_work():
     assert "s2ce.mla.prefill/" not in hlo["decode"]
 
 
+@pytest.mark.parametrize("mesh", [None, {"data": 2, "model": 2}],
+                         ids=["no_mesh", "fsdp_2x2"])
+def test_layer_scan_barriers_its_params_only_under_a_sharding_mesh(mesh):
+    """The layer scan reads each layer's weights in place from the stack
+    unless a mesh of more than one device can all-gather them: then, in
+    the cached scan (decode) and the no-cache scan (forward_lm) alike, an
+    optimization barrier named ``s2ce.scan.param_barrier`` orders the
+    gathers."""
+    import contextlib
+    from repro.dist import use_mesh
+    from repro.dist.sharding import build_rules
+    from repro.models import model_zoo as zoo
+    arch = DRV.arch_config(small())         # a stack of two routed layers
+    params = zoo.param_shapes(arch)
+    caches = jax.eval_shape(lambda: zoo.init_caches(arch, 2, 8))
+    sds = jax.ShapeDtypeStruct
+    ctx = (use_mesh(mesh, build_rules(arch, recipe="fsdp")) if mesh
+           else contextlib.nullcontext())
+    with ctx:
+        progs = {
+            "decode": jax.jit(lambda p, c, t: zoo.decode_step(p, arch, c, t))
+            .lower(params, caches, sds((2, 1), jnp.int32)),
+            "forward_lm": jax.jit(
+                lambda p, t: zoo.forward_lm(p, arch, {"tokens": t}))
+            .lower(params, sds((2, 8), jnp.int32))}
+    for name, lowered in progs.items():
+        text = lowered.as_text(debug_info=True)
+        want = 1 if mesh else 0
+        assert text.count("stablehlo.optimization_barrier") == want, name
+        assert ("s2ce.scan.param_barrier" in text) == bool(mesh), name
+
+
 def test_yarn_frequencies_and_softmax_scale_are_the_published_ones():
     from repro.configs.base import get_config
     from repro.models import attention, layers

@@ -1,5 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e chip at deployment
-widths.
+widths, and the serving decode step's layer loop reads its weights in
+place.
 
 Nothing runs: each kernel is lowered and compiled for a described (not
 attached) ``v5e:2x2`` topology, so the chip's compiler refuses here what
@@ -11,6 +12,7 @@ at import, and the persistent compilation cache is off around the compiles
 """
 
 import os
+import re
 
 import pytest
 
@@ -112,3 +114,110 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the decode step's layer loop --------------------------------------------
+
+HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def _computations(hlo: str) -> dict:
+    """Instruction lines of each computation of a compiled program's text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split()
+            cur = comps.setdefault(
+                head[1 if head[0] == "ENTRY" else 0].lstrip("%"), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _result_and_opcode(line: str):
+    rest = line.split(" = ", 1)[1]
+    if rest.startswith("("):                        # a tuple-shaped result
+        depth = 0
+        for end, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if depth == 0:
+                break
+        return rest[:end + 1], rest[end + 2:].split("(", 1)[0]
+    result, rest = rest.split(" ", 1)
+    return result, rest.split("(", 1)[0]
+
+
+FREE = {"parameter", "get-tuple-element", "tuple", "bitcast", "constant"}
+
+
+def _weight_slices_in_loops(hlo: str, stack) -> list:
+    """Instructions of a while loop's body whose result holds one layer's
+    slice of a stacked weight matrix: weights copied out of the stack.
+    Matrices are the stack's leaves of at least 64 KiB a layer; biases and
+    norm scales (a few KiB at deployment widths) are left out. A slice that
+    keeps the scan's unit layer axis does not match: the compiler stages
+    such slices into on-chip memory (async slices of the small weights at
+    these widths; at deployment widths latent decode's 2 MB absorbed
+    projections and Qwen2's k/v projections), with or without the barrier."""
+    per_layer = {(HLO_DTYPES[jnp.dtype(a.dtype).name], tuple(a.shape[1:]))
+                 for a in jax.tree.leaves(stack)
+                 if a.ndim >= 3 and a.size // a.shape[0]
+                 * jnp.dtype(a.dtype).itemsize >= 64 * 1024}
+    comps = _computations(hlo)
+    found = []
+    for body in set(re.findall(r"body=%?([\w.\-]+)", hlo)):
+        for line in comps.get(body, []):
+            if " = " not in line:
+                continue
+            result, opcode = _result_and_opcode(line)
+            shapes = {(d, tuple(int(n) for n in dims.split(",") if n))
+                      for d, dims in re.findall(r"(\w+)\[([\d,]*)\]", result)}
+            if opcode not in FREE and shapes & per_layer:
+                found.append(line.split(" = ", 1)[0].strip())
+    return found
+
+
+def _small(name: str, **widths):
+    """A serving configuration's program at tile-aligned test widths."""
+    from bench import cells
+    cfg = dict(cells.load_json(cells.BENCH_DIR / "configs" / f"{name}.json"),
+               **widths)
+    driver = cells.load_module(cells.BENCH_DIR / "drivers"
+                               / f"{cfg['driver']}.py")
+    return driver.arch_config(cfg)
+
+
+# latent attention with a held share of routed experts and shared experts
+# over a stack of two routed layers; a dense GQA decoder with q/k/v biases
+DECODERS = {
+    "mla_moe": dict(
+        name="deepseek_v2_lite_serve", hidden_size=256, intermediate_size=512,
+        num_hidden_layers=3, num_attention_heads=2, num_key_value_heads=2,
+        kv_lora_rank=128, moe_intermediate_size=256, router_experts=16,
+        n_routed_experts=4, num_experts_per_tok=2, vocab_size=1024),
+    "gqa": dict(
+        name="qwen2_1_5b_serve", hidden_size=512, intermediate_size=1024,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        vocab_size=1024),
+}
+
+
+@pytest.mark.parametrize("model", sorted(DECODERS))
+def test_decode_layer_loop_reads_weights_in_place(model, one_chip,
+                                                  no_persistent_cache):
+    """Compiled for one chip with no mesh, the decode step's layer loop
+    copies no layer's weights out of the stack: its products read the
+    stacked operand through a fused slice."""
+    from repro.models import model_zoo as zoo
+    arch = _small(**DECODERS[model])
+    place = lambda t: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    params = place(zoo.param_shapes(arch))
+    caches = place(jax.eval_shape(lambda: zoo.init_caches(arch, 4, 128)))
+    tokens = jax.ShapeDtypeStruct((4, 1), I32, sharding=one_chip)
+    hlo = jax.jit(lambda p, c, t: zoo.decode_step(p, arch, c, t)).lower(
+        params, caches, tokens).compile().as_text()
+    assert "while" in hlo
+    assert _weight_slices_in_loops(hlo, params["stack"]) == []
