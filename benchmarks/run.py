@@ -95,17 +95,17 @@ def bench_s3_offload(rows, quick):
     controller decision latency."""
     from repro.core import costmodel as cm
     from repro.core.offload import OffloadController
-    from repro.core.placement import place, standard_pipeline
-    res = {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD}
-    ops = standard_pipeline(dim=64)
+    from repro.core.placement import place_frontier, standard_pipeline
+    res = cm.ClusterSpec.edge_cloud()
+    pipe = standard_pipeline(dim=64)
     for rate in [1e3, 1e5, 1e7]:
         t0 = time.perf_counter()
-        plan, cut = place(ops, res, rate)
+        plan, frontier = place_frontier(pipe, res, rate)
         us = (time.perf_counter() - t0) * 1e6
         rows.append((f"s3_place_rate{rate:.0e}", us,
-                     f"cut={cut} lat={plan.latency_s * 1e3:.2f}ms "
+                     f"cut={len(frontier)} lat={plan.latency_s * 1e3:.2f}ms "
                      f"energy={plan.energy_w:.0f}W"))
-    ctl = OffloadController(ops, res)
+    ctl = OffloadController(pipe, res)
     ctl.initial_plan(1e4)
     t0 = time.perf_counter()
     for step in range(100):
@@ -126,7 +126,8 @@ def bench_pipeline_partition(rows, quick):
     states = pipe.init_states()
 
     def step(states, rng, cut):
-        states, out = pipe.run(states, {"x": x, "y": y, "rng": rng}, cut)
+        states, out = pipe.run(states, {"x": x, "y": y, "rng": rng},
+                               pipe.names[:cut])
         return states, out["rng"]
 
     t0 = time.perf_counter()
@@ -287,7 +288,7 @@ def bench_dag_place_dp(rows, quick):
     from repro.core import costmodel as cm
     from repro.core.pipeline import fanout_stream_graph
     from repro.core.placement import (Objective, place_frontier,
-                                      place_frontier_dp)
+                                      place_frontier_oracle)
     obj = Objective()
     # row 1: DP vs enumeration on the bench_dag_place_multipool topology
     edge_b = cm.Resource("edge_b", "edge", chips=1, flops=1e12, mem_bw=40e9,
@@ -306,9 +307,9 @@ def bench_dag_place_dp(rows, quick):
     iters = 2 if quick else 5
     t0 = time.perf_counter()
     for _ in range(iters):
-        plan_dp, frontier_dp = place_frontier_dp(g, spec, 1e4, obj)
+        plan_dp, frontier_dp = place_frontier(g, spec, 1e4, obj)
     us = (time.perf_counter() - t0) / iters * 1e6
-    plan_en, _ = place_frontier(g, spec, 1e4, obj, method="enumerate")
+    plan_en, _ = place_frontier_oracle(g, spec, 1e4, obj)
     parity = obj.score(plan_dp) <= obj.score(plan_en) * 1.0001
     rows.append(("dag_place_dp_parity", us,
                  f"{len(spec)} pools, edge={len(frontier_dp)}/"
@@ -318,8 +319,8 @@ def bench_dag_place_dp(rows, quick):
     spec_big = _dp_big_spec(8, 16)
     stats = {}
     t0 = time.perf_counter()
-    plan, frontier = place_frontier_dp(g_big, spec_big, 1e5, obj,
-                                       max_labels=256, stats=stats)
+    plan, frontier = place_frontier(g_big, spec_big, 1e5, obj,
+                                    max_labels=256, stats=stats)
     us = (time.perf_counter() - t0) * 1e6
     rows.append(("dag_place_dp_100ops", us,
                  f"{len(spec_big)} pools, edge={len(frontier)}/100 ops, "
@@ -361,8 +362,8 @@ def bench_adaptive_codec_replan(rows, quick):
                  f"{n_plans} plans, {n_plans / us * 1e6:.0f} plans/s, "
                  f"codec={plan.uplink_codec}"))
     # one full escalate/de-escalate cycle through the controller
-    ctl = OffloadController(g.costs(), spec, graph=g, codec="topk_int8_ef",
-                            sla_spec=sla, cooldown=1, codec_cooldown=1)
+    ctl = OffloadController(g, spec, codec="topk_int8_ef", sla_spec=sla,
+                            cooldown=1, codec_cooldown=1)
     ctl.initial_plan(5e6)
     t0 = time.perf_counter()
     for step, rate in enumerate([1e3, 5e6] * 5):
@@ -521,7 +522,8 @@ def bench_pipeline_fuse_xla(rows, quick):
         rng = jax.random.PRNGKey(0)
 
         def step(states, rng):
-            states, out = pipe.run(states, {"x": x, "y": y, "rng": rng}, 4)
+            states, out = pipe.run(states, {"x": x, "y": y, "rng": rng},
+                                   pipe.names[:4])
             return states, out["rng"]
 
         states, rng = step(states, rng)       # compile
@@ -594,7 +596,7 @@ def bench_serve_prefill_edge_decode(rows, quick):
     iters = 3 if quick else 10
     t0 = time.perf_counter()
     for _ in range(iters):
-        plan, frontier = place_frontier(g, spec, 3e3, obj, method="dp")
+        plan, frontier = place_frontier(g, spec, 3e3, obj)
     us = (time.perf_counter() - t0) / iters * 1e6
     split = (plan.assignment.get("prefill") == "cloud0"
              and plan.assignment.get("decode") == "edge0")
@@ -624,7 +626,7 @@ def bench_train_op_placed(rows, quick):
     iters = 3 if quick else 10
     t0 = time.perf_counter()
     for _ in range(iters):
-        plan, frontier = place_frontier(g, spec, 1e3, obj, method="dp")
+        plan, frontier = place_frontier(g, spec, 1e3, obj)
     us = (time.perf_counter() - t0) / iters * 1e6
     rows.append(("train_op_placed", us,
                  f"pool={plan.assignment.get(op.name)} "
@@ -650,7 +652,7 @@ def bench_fleet(rows, quick):
 
     def controller(cool=5):
         return OffloadController(
-            standard_stream_pipeline(dim=8).costs(), spec,
+            standard_stream_pipeline(dim=8), spec,
             codec=pick_codec(sla).name, sla_spec=sla, cooldown=cool)
 
     # admission: probe + initial plan + ledger booking, per tenant

@@ -9,11 +9,6 @@ side of a downward-closed frontier cut across *all* pools of its kind,
 pricing every crossing link with codec-compressed bytes and DAG latency
 as the critical path.
 
-The old two-pool style — a flat ``{"edge": ..., "cloud": ...}`` dict
-collapsed by the ``edge_cloud_pools`` shim to the first pool of each
-kind — still works everywhere but is DEPRECATED: it ignores extra pools
-and their links. Prefer building a ``ClusterSpec``.
-
 The final section demonstrates **rate-adaptive codec control**: the
 offload controller re-runs codec admission on every replan from
 windowed SLA telemetry, escalating the uplink codec when the link
@@ -26,8 +21,8 @@ codec trajectory of a saturating rate ramp.
 from repro.core import costmodel as cm
 from repro.core import pipeline as pl
 from repro.core.offload import OffloadController
-from repro.core.placement import (Objective, place, place_frontier,
-                                  place_graph_exhaustive, standard_pipeline)
+from repro.core.placement import (Objective, place_frontier,
+                                  place_graph_exhaustive)
 from repro.core.sla import SLA, SLATracker, pick_codec
 from repro.streams.feeder import StreamFeeder
 from repro.streams.generators import HyperplaneStream
@@ -83,19 +78,9 @@ def main():
               f"feasible={plan.feasible} "
               f"oracle_match={obj.score(plan) <= obj.score(oracle)*1.0001}")
 
-    # -- deprecated two-pool shim (still works, collapses the topology) ---
-    print("\n== deprecated flat-dict path (first pool of each kind) ==")
-    resources = {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD}
-    ops = standard_pipeline(dim=64, sample_rate=0.25)
-    for rate in (1e3, 1e6):
-        plan, cut = place(ops, resources, rate, Objective(energy_weight=0.1))
-        print(f"  rate {rate:9.0f} ev/s -> prefix cut {cut} "
-              f"latency={plan.latency_s * 1e3:6.2f}ms")
-
     # -- dynamic offload under a 40x burst, multi-pool plan identity ------
     print("\n== dynamic offload under a 40x burst ==")
-    ctl = OffloadController(g.costs(), cluster, graph=g, cooldown=2,
-                            codec=codec.name)
+    ctl = OffloadController(g, cluster, cooldown=2, codec=codec.name)
     sla = SLATracker(SLA(max_latency_s=0.05))
     ctl.initial_plan(5e3)
     rates = [5e3] * 10 + [2e5] * 10 + [5e3] * 10      # burst in the middle
@@ -123,9 +108,8 @@ def main():
     start = pick_codec(adaptive_sla, report={"uplink_utilization": 0.0,
                                              "violation_rate": 0.0})
     actl = OffloadController(
-        pipe.costs(), cm.ClusterSpec.edge_cloud(), graph=pipe,
-        codec=start.name, sla_spec=adaptive_sla,
-        cooldown=2, codec_cooldown=4)
+        pipe, cm.ClusterSpec.edge_cloud(), codec=start.name,
+        sla_spec=adaptive_sla, cooldown=2, codec_cooldown=4)
     ramp = [1e4] * 6 + [8e7] * 6 + [1e4] * 6       # saturate, then recover
     actl.initial_plan(ramp[0])
     for step, rate in enumerate(ramp):

@@ -68,11 +68,11 @@ def main():
     obj = Objective()
     print("\n== placement DP prices KV state against mem_cap ==")
     tiny = build_cluster(edge_mem=1e3)       # KV cache cannot fit
-    plan, _ = place_frontier(graph, tiny, 1e3, obj, method="dp")
+    plan, _ = place_frontier(graph, tiny, 1e3, obj)
     print(f"  edge mem 1KB  -> {plan.assignment} (edge excluded)")
     assert plan.assignment == {"prefill": "cloud0", "decode": "cloud0"}
     roomy = build_cluster()
-    plan, frontier = place_frontier(graph, roomy, 3e3, obj, method="dp")
+    plan, frontier = place_frontier(graph, roomy, 3e3, obj)
     print(f"  edge mem 4GB  -> {plan.assignment} at 3000 ev/s "
           f"(pod saturated: cloud-prefill/edge-decode)")
     assert plan.assignment == {"prefill": "cloud0", "decode": "edge0"}
@@ -108,7 +108,7 @@ def main():
     print("\n== saturating decode ramp: KV codec escalation ==")
     sla = SLA(max_latency_s=0.5, error_budget=0.8)
     ctl = OffloadController(
-        graph.costs(), roomy, obj, graph=graph, codec="identity",
+        graph, roomy, obj, codec="identity",
         sla_spec=sla, codec_candidates=[c.name for c in KV_CODECS],
         cooldown=1, codec_cooldown=2)
     ramp = [1e3] * 3 + [1.8e3, 2.4e3, 3.2e3] + [3.2e3] * 3 + [1e3] * 4
@@ -133,7 +133,7 @@ def main():
     print("\n== train step as a placement-priced op ==")
     top = dl_train_op(cfg, adamw(1e-3), batch_size=4, seq_len=64)
     from repro.core.pipeline import OpGraph
-    tplan, _ = place_frontier(OpGraph([top]), roomy, 1e3, obj, method="dp")
+    tplan, _ = place_frontier(OpGraph([top]), roomy, 1e3, obj)
     print(f"  {top.name}: state={top.cost.state_bytes / 1e6:.2f}MB "
           f"edge_capable={top.cost.edge_capable} "
           f"-> {tplan.assignment}")

@@ -10,11 +10,11 @@ Resources are heterogeneous pools (cloud TPU pods, edge nodes); a
 codec compressing bytes on that link); operators are stream-pipeline
 stages with per-event flops/bytes/output-bytes costs.
 
-DAG plan latency is the **critical path** over the op DAG: each op
+Every plan is priced by :func:`evaluate_graph_plan` over the job's op
+graph. Plan latency is the **critical path** over that graph: each op
 contributes its compute latency, each crossing flow edge the latency of
 the link it rides. For a linear chain the critical path is the whole
-chain (one path), so chain plans price identically to the historical
-per-op sum — the PR 2/3 parity the tests pin down.
+chain (one path), the per-op sum.
 """
 
 from __future__ import annotations
@@ -134,11 +134,11 @@ class ClusterSpec(Mapping):
         return cls(dict(resources))
 
     @classmethod
-    def edge_cloud(cls, edge: Resource = EDGE_NODE,
-                   cloud: Resource = CLOUD_POD) -> "ClusterSpec":
-        """The classic one-edge/one-cloud topology (back-compat shim for
-        pre-ClusterSpec call sites; prefer declaring pools + links)."""
-        return cls({edge.name: edge, cloud.name: cloud})
+    def edge_cloud(cls) -> "ClusterSpec":
+        """One ``EDGE_NODE`` pool and one ``CLOUD_POD`` with derived
+        default links — what a :class:`~repro.core.orchestrator.StreamJob`
+        given no cluster runs on."""
+        return cls([EDGE_NODE, CLOUD_POD])
 
     # -- Mapping interface over pools --------------------------------------
     def __getitem__(self, name: str) -> Resource:
@@ -346,7 +346,7 @@ def op_placement_terms(op: OperatorCost, res: Resource, rate: float
     """The per-(op, pool) scalars every placement evaluation accumulates:
     ``(utilization, node compute latency, energy watts)``. Shared by
     :func:`evaluate_graph_plan` and the placement DP
-    (:func:`repro.core.placement.place_frontier_dp`) so the two paths
+    (:func:`repro.core.placement.place_frontier`) so the two paths
     price an op on a pool with bit-identical arithmetic — the DP's
     incremental bookkeeping must reproduce the evaluator's numbers, not
     merely approximate them."""
@@ -370,62 +370,6 @@ class PipelinePlan:
     # is part of the plan search (placement.frontier_plans codecs=...);
     # None -> whatever the ClusterSpec's links declare
     uplink_codec: Optional[str] = None
-
-
-def evaluate_plan(ops: List[OperatorCost], assign: Dict[str, str],
-                  resources: ResourcesLike, rate: float,
-                  source: Optional[str] = None) -> PipelinePlan:
-    """Evaluate a linear pipeline: stage order = list order; data crosses
-    the network wherever consecutive stages sit on different pools, priced
-    on the connecting :class:`Link` (codec-compressed bytes, link latency).
-
-    ``source`` names the pool the stream *originates* at — by default the
-    spec's first edge pool (S2CE ingests at the edge gateway), so an
-    all-cloud plan pays the raw-event uplink instead of getting it for
-    free. Without this charge every placement degenerates to all-cloud
-    and the cut never moves. Pass ``source=""`` to disable (data already
-    at rest in the cloud).
-    """
-    spec = ClusterSpec.of(resources)
-    if source is None:
-        source = spec.default_source()
-    plan = PipelinePlan(dict(assign))
-    latency = 0.0
-    energy = 0.0
-    link_bytes: Dict[Tuple[str, str], float] = {}
-    per_res_util: Dict[str, float] = {r: 0.0 for r in spec}
-    prev = source if source else None
-    in_bytes = ops[0].bytes_per_event if ops else 0.0
-    for op in ops:
-        rname = assign[op.name]
-        res = spec.pools[rname]
-        if not op.edge_capable and res.kind == "edge":
-            plan.feasible = False
-            plan.notes.append(f"{op.name} not edge-capable")
-        u = stage_time(op, res, rate)
-        per_res_util[rname] = per_res_util.get(rname, 0.0) + u
-        latency += op.flops_per_event / res.total_flops
-        energy += u * res.energy_w * res.chips
-        if prev is not None and prev != rname:
-            ln = spec.link(prev, rname)
-            wire = ln.wire_bytes(in_bytes)
-            link_bytes[(prev, rname)] = (link_bytes.get((prev, rname), 0.0)
-                                         + wire)
-            latency += ln.latency
-            energy += wire * rate * ln.energy_per_byte
-        in_bytes = op.out_bytes_per_event
-        prev = rname
-        if op.state_bytes > res.mem_cap * res.chips:
-            plan.feasible = False
-            plan.notes.append(f"{op.name} state exceeds {rname} memory")
-    plan.utilization = per_res_util
-    plan.latency_s = latency
-    plan.link_utilization = {
-        key: b * rate / spec.link(*key).bw for key, b in link_bytes.items()}
-    plan.uplink_utilization = (max(plan.link_utilization.values())
-                               if plan.link_utilization else 0.0)
-    plan.energy_w = energy
-    return _finalize_capacity(plan)
 
 
 def _finalize_capacity(plan: PipelinePlan) -> PipelinePlan:
@@ -505,10 +449,9 @@ def evaluate_graph_plan(ops: List[OperatorCost],
     Plan latency is the **critical path** of the op DAG: each op node
     weighs its compute latency, each crossing edge adds the latency of
     the link it rides, and the plan's latency is the longest source-to-
-    sink path. Parallel branches therefore overlap instead of summing —
-    and a linear chain (one path) reproduces the historical per-op-sum
-    price exactly, which keeps chain plans parity-identical to
-    :func:`evaluate_plan`.
+    sink path. Parallel branches therefore overlap instead of summing;
+    a linear chain (one path) prices as the sum of its ops' compute
+    latencies and its crossings' link latencies.
 
     ``source`` names the pool the stream originates at (default: the
     spec's first edge pool); ``source_consumers`` are the ops that read

@@ -21,7 +21,7 @@ Three layers:
 * :class:`FleetScheduler` — admission control and fleet-batched replan
   arbitration over :class:`~repro.core.offload.OffloadController`
   handles. Admission probes the tenant's best plan (the controller's
-  own placement engine, ``place_frontier(method="dp")`` for DAGs) under
+  own placement engine, ``place_frontier``) under
   residual capacity and REJECTS (or queues) a tenant whose best plan
   cannot meet its SLA — with a loud reason, never a silent degrade.
   Replans batch globally: each arbitration pass collects every tenant's

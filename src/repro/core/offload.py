@@ -6,12 +6,15 @@ tracker reports violations. Replanning uses the same cost model as static
 placement; hysteresis (enter/exit thresholds + cooldown) prevents
 thrashing when the rate oscillates around a cut point.
 
-Decisions carry the full *assignment* — op name -> pool name over the
-job's :class:`~repro.core.costmodel.ClusterSpec` — plus the ``frontier``
-view: the downward-closed set of op names resident on *any* edge pool.
-For a linear pipeline the frontier is exactly the prefix ``ops[:cut]``
-and ``cut`` keeps its old meaning; for an operator DAG the frontier can
-hold parallel branches independently and ``cut`` reports its size.
+Every replan is one :func:`~repro.core.placement.place_frontier` call
+over the job's op graph. Decisions carry the full *assignment* — op
+name -> pool name over the job's
+:class:`~repro.core.costmodel.ClusterSpec` — plus the ``frontier`` view:
+the downward-closed set of op names resident on *any* edge pool, and
+``cut``, its size. For a linear
+:class:`~repro.core.pipeline.Pipeline` the frontier is the prefix
+``ops[:cut]``; for an operator DAG it can hold parallel branches
+independently.
 Hysteresis and the migration count key on **plan identity** — the pool
 assignment (which pool each op runs on, not merely which side of the
 cut) together with the uplink codec — so a multi-pool rebalance that
@@ -40,8 +43,7 @@ from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 from repro.core.costmodel import (ClusterSpec, MigrationCost, OperatorCost,
                                   PipelinePlan, ResourcesLike,
                                   migration_cost)
-from repro.core.placement import (Objective, place, place_frontier,
-                                  stale_pools)
+from repro.core.placement import Objective, place_frontier, stale_pools
 from repro.core.sla import SLA, SLATracker
 from repro.core.sla import codec_candidates as sla_codec_candidates
 from repro.core.spans import span
@@ -66,11 +68,10 @@ class OffloadDecision:
 
 @dataclass
 class OffloadController:
-    ops: List[OperatorCost]
+    # the job's OpGraph (a linear Pipeline is the chain graph)
+    graph: object
     resources: ResourcesLike
     objective: Objective = field(default_factory=Objective)
-    # an OpGraph to plan over frontier cuts; None -> prefix cuts over `ops`
-    graph: Optional[object] = None
     # uplink codec the plan executes with (part of plan identity)
     codec: str = "identity"
     # rate-adaptive codec control: the SLA whose error budget gates
@@ -82,12 +83,6 @@ class OffloadController:
     headroom: float = 1.3      # replan when rate moves x1.3 outside band
     cooldown: int = 5          # min decisions between migrations
     codec_cooldown: int = 10   # min decisions between codec swaps
-    # placement engine for DAG replans ("auto" | "enumerate" | "dp").
-    # The controller replans inside the control loop, so it defaults to
-    # the polynomial DP — cost-identical to the enumeration with the
-    # same canonical tie-break, but it stays fast when the graph or the
-    # ClusterSpec grows past toy sizes.
-    placement_method: str = "dp"
     planned_rate: float = 0.0
     cut: int = 0
     frontier: FrozenSet[str] = frozenset()
@@ -106,6 +101,11 @@ class OffloadController:
                 self.codec_candidates = [self.codec]
         if self.codec not in self.codec_candidates:
             self.codec_candidates = [self.codec, *self.codec_candidates]
+
+    @property
+    def ops(self) -> List[OperatorCost]:
+        """The cost-model view of the graph (measured costs included)."""
+        return self.graph.costs()
 
     def set_resources(self, resources: ResourcesLike) -> None:
         """Swap the topology replans run over. The fleet scheduler calls
@@ -137,20 +137,8 @@ class OffloadController:
         the codec currently in force). ``plan.uplink_codec`` records the
         winning codec."""
         codecs = list(codecs) if codecs else [self.codec]
-        if self.graph is not None:
-            plan, _ = place_frontier(self.graph, self.resources, rate,
-                                     self.objective, codecs=codecs,
-                                     method=self.placement_method)
-        else:
-            plan = None
-            best_score = float("inf")
-            for cname in codecs:
-                spec = self.resources.with_uplink_codec(cname)
-                cand, _ = place(self.ops, spec, rate, self.objective)
-                cand.uplink_codec = cname
-                s = self.objective.score(cand)
-                if plan is None or s < best_score:
-                    plan, best_score = cand, s
+        plan, _ = place_frontier(self.graph, self.resources, rate,
+                                 self.objective, codecs=codecs)
         return plan, self._frontier_of(plan.assignment)
 
     def probe_plan(self, rate: float):

@@ -14,8 +14,10 @@ and an SLA. The orchestrator:
      to every edge->cloud link,
   2. executes the planned partition: the frontier (ops resident on any
      edge pool; a prefix for linear pipelines) as the edge segment, the
-     rest as the cloud segment (core/pipeline), applying the chosen
-     codec's wire round-trip to batches crossing the uplink,
+     rest as the cloud segment, through the one
+     ``OpGraph.run(states, batch, frontier, uplink=...)`` call
+     (core/pipeline), applying the chosen codec's wire round-trip to
+     batches crossing the uplink,
   3. monitors rate + SLA, *re-plans* via the offload controller, and
      re-partitions the graph when the assignment migrates — including
      **codec migrations**: the controller re-runs codec admission
@@ -46,9 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.costmodel import CLOUD_POD, EDGE_NODE, ClusterSpec, Resource
+from repro.core.costmodel import ClusterSpec
 from repro.core.offload import OffloadController
-from repro.core.pipeline import OpGraph, Pipeline, standard_stream_pipeline
+from repro.core.pipeline import OpGraph, standard_stream_pipeline
 from repro.core.placement import Objective
 from repro.core.sla import SLA, SLATracker, codec_candidates, pick_codec
 from repro.core.spans import span
@@ -68,8 +70,8 @@ class StreamJob:
     sample_rate: float = 0.5
     drift_detector: str = "ddm"          # ddm|eddm|ph|adwin
     # full cluster topology (any number of edge pools / cloud pods with
-    # explicit links); None -> the classic two-pool spec built from
-    # edge_resource/cloud_resource below (kept for back-compat)
+    # explicit links); None (and no membership) -> the one-edge/one-cloud
+    # ClusterSpec.edge_cloud()
     cluster: Optional[ClusterSpec] = None
     # live topology: a core/membership.MembershipDirectory whose events
     # (pools joining/leaving/failing, probe-driven latency rewrites) the
@@ -77,8 +79,6 @@ class StreamJob:
     # a directory that emits no events runs bitwise identically to the
     # equivalent static spec
     membership: Optional[object] = None
-    edge_resource: Resource = EDGE_NODE
-    cloud_resource: Resource = CLOUD_POD
     objective: Objective = field(default_factory=Objective)
     # user-supplied operator graph (linear Pipeline or fan-out OpGraph);
     # None -> the standard S2CE chain
@@ -133,7 +133,7 @@ class Orchestrator:
     def __init__(self, job: StreamJob):
         self.job = job
         # the cluster topology placement runs over: the job's ClusterSpec,
-        # or the classic two-pool spec from edge/cloud resources. The SLA
+        # the live directory's, or ClusterSpec.edge_cloud(). The SLA
         # error budget picks the cheapest admissible uplink codec, which
         # fills every uplink that doesn't declare its own (pricing) AND
         # is applied to batches crossing segments at runtime (execution).
@@ -151,8 +151,7 @@ class Orchestrator:
             self._topo_sub = self.membership.subscribe()
         else:
             spec = (ClusterSpec.of(job.cluster) if job.cluster is not None
-                    else ClusterSpec.edge_cloud(job.edge_resource,
-                                                job.cloud_resource))
+                    else ClusterSpec.edge_cloud())
         # the user-declared topology, BEFORE the blanket codec attach:
         # rate-adaptive replans re-derive per-candidate specs from it
         # (user-declared per-link codecs always win over the blanket)
@@ -177,11 +176,6 @@ class Orchestrator:
         self.pipeline = job.pipeline or standard_stream_pipeline(
             job.dim, sample_rate=job.sample_rate,
             drift_detector=job.drift_detector)
-        # a Pipeline partitions at prefix cuts (plans identical to the
-        # linear IR); any other OpGraph partitions at frontier cuts
-        self.is_graph = not isinstance(self.pipeline, Pipeline)
-        # the cost model prices the SAME op list the executor runs
-        self.ops = self.pipeline.costs()
         # every budget-admissible codec is a replan-time candidate: the
         # controller re-runs admission against windowed SLA telemetry on
         # each replan event and may migrate the codec (a zero budget
@@ -190,8 +184,7 @@ class Orchestrator:
             c.name for c in codec_candidates(
                 job.sla, candidates=self._codec_ladder)]
         self.controller = OffloadController(
-            self.ops, self._base_cluster, job.objective,
-            graph=self.pipeline if self.is_graph else None,
+            self.pipeline, self._base_cluster, job.objective,
             codec=self.codec.name, sla_spec=job.sla,
             codec_candidates=self.codec_candidates)
         self.sla = SLATracker(job.sla, window=job.sla_window)
@@ -384,8 +377,6 @@ class Orchestrator:
         measured, notes = selftune.measure_operator_costs(self.pipeline, bd)
         if measured:
             self.pipeline.set_measured_costs(measured)
-            self.ops = self.pipeline.costs()
-            self.controller.ops = self.ops
         self.metrics.decisions.append(
             f"0:measured-costs {len(measured)}/{len(self.pipeline.ops)} ops"
             + (f" ({len(notes)} kept declared)" if notes else ""))
@@ -444,14 +435,9 @@ class Orchestrator:
                 # splitting here makes randomness advance regardless of the
                 # op set
                 bd["rng"] = jax.random.fold_in(self._root_rng, step)
-            if self.is_graph:
-                self.states, out = self.pipeline.run(self.states, bd,
-                                                     self.frontier,
-                                                     uplink=self._uplink)
-            else:
-                self.states, out = self.pipeline.run(self.states, bd,
-                                                     self.cut,
-                                                     uplink=self._uplink)
+            self.states, out = self.pipeline.run(self.states, bd,
+                                                 self.frontier,
+                                                 uplink=self._uplink)
             self.metrics.cuts.append(self.cut)
             self.metrics.assignments.append(self.frontier)
             self.metrics.codecs.append(self.codec.name)

@@ -19,10 +19,10 @@ complement on the cloud, and the cost model prices the uplink per crossing
 edge (``out_bytes_per_event`` of each edge-side producer feeding a cloud
 consumer) instead of at one cut point.
 
-:class:`Pipeline` is retained as the linear special case: an ordered op
-list whose frontiers are exactly the prefix cuts ``ops[:k]``, with the
-same ``run(states, batch, cut)`` API, prefix-cut placement, and plan
-costs as before — every existing call site keeps working unchanged.
+:class:`Pipeline` is the chain graph: an ordered op list whose ops need
+not declare channels, whose frontiers are exactly the prefixes
+``ops[:k]``, and which is placed, priced and run like any other graph —
+``run(states, batch, frontier)`` with a prefix frontier.
 
 Cut-invariance: in the default ``fuse="op"`` mode each op is its own XLA
 compilation unit and segments compose the *shared* per-op executables, so
@@ -270,18 +270,6 @@ class OpGraph:
         pairs — the index view of :attr:`flow_edges` (the edges the cost
         model prices bytes on)."""
         return self._flow_pairs
-
-    def count_frontiers(self, limit: Optional[int] = None) -> int:
-        """Number of downward-closed frontiers, enumerated lazily and
-        capped at ``limit`` (the dispatch heuristic in
-        ``placement.place_frontier`` needs "more than N?", never the
-        exact — potentially exponential — count)."""
-        n = 0
-        for _ in self.frontiers():
-            n += 1
-            if limit is not None and n >= limit:
-                break
-        return n
 
     def costs(self) -> List[OperatorCost]:
         """The cost-model view — what placement/offload optimize over.
@@ -538,19 +526,13 @@ class OpGraph:
 
 
 class Pipeline(OpGraph):
-    """An ordered list of :class:`Op`, executable under any prefix cut —
-    the linear special case of :class:`OpGraph`.
+    """An ordered list of :class:`Op` — the chain :class:`OpGraph`.
 
     The dependency structure is the chain itself (op ``i`` precedes op
-    ``i+1``), so frontiers are exactly the prefixes and placement reduces
-    to the prefix-cut search; channel declarations are not required, and
-    each op receives the full batch dict exactly as before."""
-
-    def __init__(self, ops: Sequence[Op], fuse: str = "op"):
-        ops = tuple(ops)
-        if not ops:
-            raise ValueError("pipeline needs at least one op")
-        super().__init__(ops, fuse=fuse)
+    ``i+1``), so its frontiers are exactly the prefixes
+    ``names[:k]`` (:meth:`check_frontier` refuses any other set);
+    channel declarations are not required, and each op receives the
+    full batch dict."""
 
     def _build_deps(self):
         # a chain: each op depends on its predecessor; bytes flow along
@@ -559,22 +541,13 @@ class Pipeline(OpGraph):
         self._parents = tuple(frozenset(() if i == 0 else (i - 1,))
                               for i in range(n))
         # prefix cuts only: the linear chain keeps the strict relation
-        # even for downlink-ok ops (a non-prefix edge set has no `cut`).
+        # even for downlink-ok ops.
         self._closure = self._parents
         self._flow_pairs = tuple((i, i + 1) for i in range(n - 1))
         self.flow_edges = tuple((self.ops[i].name, self.ops[i + 1].name)
                                 for i in range(n - 1))
         self.source_reads = ()
         self.source_consumers = (self.ops[0].name,)
-
-    @property
-    def source_bytes_per_event(self) -> float:
-        return self.cost_of(self.ops[0].name).bytes_per_event
-
-    @property
-    def n_cuts(self) -> int:
-        """Valid cuts are 0..len(ops): ops[:k] edge, ops[k:] cloud."""
-        return len(self.ops) + 1
 
     def _apply(self, i: int, states: Dict[str, Any], env: Batch,
                call: Optional[Callable] = None
@@ -585,27 +558,6 @@ class Pipeline(OpGraph):
         st, env = (call or self._op_fn(i))(states[op.name], env)
         states[op.name] = st
         return states, env
-
-    def run(self, states: Dict[str, Any], batch: Batch, cut: int,
-            uplink: Optional[Callable[[Batch], Batch]] = None
-            ) -> Tuple[Dict[str, Any], Batch]:
-        """Execute under prefix cut ``cut``: ops[:cut] as the edge segment,
-        ops[cut:] as the cloud segment (either may be empty). ``uplink``
-        (optional) transforms the batch where it crosses the segments —
-        the orchestrator's codec hook."""
-        if not 0 <= cut <= len(self.ops):
-            raise ValueError(f"cut {cut} outside [0, {len(self.ops)}]")
-        return self._run_segments(
-            states, batch, (tuple(range(0, cut)),
-                            tuple(range(cut, len(self.ops)))), uplink)
-
-    def run_reference(self, states: Dict[str, Any], batch: Batch
-                      ) -> Tuple[Dict[str, Any], Batch]:
-        """Unpartitioned execution: the whole chain as one (cloud) segment
-        — under the default ``fuse="op"`` that is the per-op composition
-        at cut 0, not a single fused jit (``fuse="xla"`` fuses it). Any
-        cut must reproduce this bitwise."""
-        return self.run(states, batch, cut=0)
 
 
 # ---------------------------------------------------------------------------

@@ -9,38 +9,36 @@ model). Ops declaring ``OperatorCost.downlink_ok`` relax this per
 consumer: their cloud->edge crossing is a priced *downlink* (cloud-
 prefill/edge-decode serving), so closure is taken under
 ``OpGraph.closure_parent_indices`` — identical to the full hazard
-relation everywhere else. For a linear chain the downward-closed sets
-are the prefixes, so
-:func:`place` searches all prefix cuts exactly (unchanged from the
-linear IR); for an operator DAG over a :class:`ClusterSpec` there are
-two engines behind :func:`place_frontier`:
+relation everywhere else. Every job is an
+:class:`~repro.core.pipeline.OpGraph` (a linear
+:class:`~repro.core.pipeline.Pipeline` is the chain graph, whose
+downward-closed sets are exactly its prefixes), placed over a
+:class:`ClusterSpec` by one engine, :func:`place_frontier`: a
+label-correcting dynamic program over topological prefixes of the
+frontier lattice. Ops are placed one at a time in graph order; a label
+carries exactly the state the cost model's forward sweep needs
+(per-pool utilization, per-link bytes, finish times of ops that still
+feed unplaced consumers, per-producer shipped-pool sets for multicast
+dedup, energy) and labels that agree on the *discrete* part of that
+state (the live frontier signature) are pruned by Pareto dominance over
+the continuous part — sound because every aggregate enters the score
+and the feasibility checks monotonically. An admissible lower bound
+against a greedy incumbent prunes further. Label counts stay polynomial
+on the chain-like graphs real jobs are, so the search reaches 100+ ops
+x dozens of pools (the ``dag_place_dp_*`` benchmark rows).
 
-  * **enumeration** (:func:`frontier_plans`): every downward-closed
-    *frontier* of the graph x every within-kind pool assignment
-    (frontier ops across edge pools, the complement across cloud pods)
-    x every codec candidate — which covers exactly the backhaul-free
-    assignments, so the search provably matches the exhaustive all-
-    assignments oracle (:func:`place_graph_exhaustive`). Exponential in
-    op count: the differential-test twin, not the production path.
-  * **dynamic program** (:func:`place_frontier_dp`): a label-correcting
-    DP over topological prefixes of the frontier lattice. Ops are placed
-    one at a time in graph order; a label carries exactly the state the
-    cost model's forward sweep needs (per-pool utilization, per-link
-    bytes, finish times of ops that still feed unplaced consumers,
-    per-producer shipped-pool sets for multicast dedup, energy) and
-    labels that agree on the *discrete* part of that state (the live
-    frontier signature) are pruned by Pareto dominance over the
-    continuous part — sound because every aggregate enters the score and
-    the feasibility checks monotonically. An admissible lower bound
-    against a greedy incumbent prunes further. The DP returns a
-    cost-identical plan to the enumeration on every DAG (property-tested
-    against the oracle) at polynomial label counts on the chain-like
-    graphs real jobs are, which lifts the search ceiling from ~7 ops to
-    100+ ops x dozens of pools (the ``dag_place_dp_*`` benchmark rows).
+Two exponential oracles check it, in tests and benchmark rows only:
 
-Both engines share one canonical tie-break — (score, |frontier|, codec
-faithfulness, pool-index tuple) — so equal-cost optima resolve
-identically and a controller switching engines does not phantom-migrate.
+  * :func:`place_frontier_oracle` walks every downward-closed frontier
+    x every within-kind pool assignment x every codec candidate
+    (:func:`frontier_plans`) — exactly the backhaul-free assignments —
+    and must return the engine's plan, tie direction included;
+  * :func:`place_graph_exhaustive` tries every assignment of every op
+    to every pool.
+
+The engine and the frontier oracle share one canonical tie-break —
+(score, |frontier|, codec faithfulness, pool-index tuple) — so
+equal-cost optima resolve identically.
 """
 
 from __future__ import annotations
@@ -52,9 +50,9 @@ from typing import (Dict, FrozenSet, Iterator, List, Optional, Sequence,
                     Tuple)
 
 from repro.core.costmodel import (ClusterSpec, OperatorCost, PipelinePlan,
-                                  Resource, ResourcesLike,
-                                  evaluate_graph_plan, evaluate_plan,
+                                  ResourcesLike, evaluate_graph_plan,
                                   op_placement_terms)
+from repro.core.pipeline import Op, Pipeline
 
 
 @dataclass
@@ -71,48 +69,6 @@ class Objective:
                 + self.uplink_weight * plan.uplink_utilization)
 
 
-def edge_cloud_pools(resources: ResourcesLike
-                     ) -> Tuple[Resource, Resource]:
-    """The (edge, cloud) pool pair two-pool placement runs over.
-
-    .. deprecated::
-        This is the thin back-compat shim for the flat two-pool world:
-        it collapses a :class:`ClusterSpec` (or legacy resource dict) to
-        the *first* pool of each kind, ignoring any further pools and
-        their links — calling it emits a ``DeprecationWarning``. New
-        code should pass a ``ClusterSpec`` to :func:`place_frontier`,
-        which places across every pool. The prefix-cut engine
-        (:func:`place`/:func:`prefix_cut_plans`) still collapses through
-        the same rule internally (it IS the two-pool engine) without
-        warning on every replan.
-
-    Raises a clear ``ValueError`` when either kind is missing — instead
-    of the bare ``StopIteration`` a ``next()`` over an ill-formed
-    resource dict used to surface.
-    """
-    import warnings
-    warnings.warn(
-        "edge_cloud_pools is the deprecated two-pool shim: it collapses "
-        "the topology to the FIRST pool of each kind, ignoring further "
-        "pools and their links; pass the ClusterSpec to place_frontier "
-        "instead", DeprecationWarning, stacklevel=2)
-    return _first_edge_cloud(resources)
-
-
-def _first_edge_cloud(resources: ResourcesLike
-                      ) -> Tuple[Resource, Resource]:
-    """The collapse rule behind :func:`edge_cloud_pools`, warning-free
-    for the prefix-cut engine's own use."""
-    spec = ClusterSpec.of(resources)
-    edges, clouds = spec.edge_pools, spec.cloud_pools
-    if not edges or not clouds:
-        kinds = sorted({r.kind for r in spec.values()})
-        raise ValueError(
-            "prefix-cut placement needs at least one 'edge' and one "
-            f"'cloud' pool; resource dict has kinds {kinds or '(empty)'}")
-    return edges[0], clouds[0]
-
-
 def stale_pools(assignment: Dict[str, str], resources: ResourcesLike
                 ) -> List[str]:
     """The pools ``assignment`` references that no longer exist in
@@ -121,38 +77,6 @@ def stale_pools(assignment: Dict[str, str], resources: ResourcesLike
     never silently hold) before the next batch executes."""
     spec = ClusterSpec.of(resources)
     return sorted({p for p in assignment.values() if p not in spec.pools})
-
-
-def prefix_cut_plans(ops: List[OperatorCost], resources: ResourcesLike,
-                     rate: float):
-    """All plans of the form: stages[:k] on edge, stages[k:] on cloud.
-    Two-pool only (first pool of each kind, the deprecated
-    :func:`edge_cloud_pools` collapse rule)."""
-    edge, cloud = _first_edge_cloud(resources)
-    for k in range(len(ops) + 1):
-        assign = {op.name: (edge.name if i < k else cloud.name)
-                  for i, op in enumerate(ops)}
-        yield k, evaluate_plan(ops, assign, resources, rate)
-
-
-def place(ops: List[OperatorCost], resources: ResourcesLike,
-          rate: float, objective: Optional[Objective] = None
-          ) -> Tuple[PipelinePlan, int]:
-    """Best prefix-cut placement. Returns (plan, cut_index)."""
-    objective = objective or Objective()
-    best, best_k, best_score = None, 0, float("inf")
-    for k, plan in prefix_cut_plans(ops, resources, rate):
-        s = objective.score(plan)
-        if s < best_score:
-            best, best_k, best_score = plan, k, s
-    if best is None or not best.feasible:
-        # all-cloud fallback (always structurally valid; may still be
-        # infeasible under extreme rates — caller must check .feasible)
-        _, cloud = _first_edge_cloud(resources)
-        assign = {op.name: cloud.name for op in ops}
-        best = evaluate_plan(ops, assign, resources, rate)
-        best_k = 0
-    return best, best_k
 
 
 def _check_state_count(what: str, n_pools: int, n_ops: int,
@@ -167,26 +91,20 @@ def _check_state_count(what: str, n_pools: int, n_ops: int,
         raise ValueError(
             f"{what} would enumerate {n_pools}^{n_ops} (~1e{est10:.0f}) "
             f"assignments, over the max_states={max_states} cap; it is "
-            "an exhaustive test oracle — use place/place_frontier for "
-            "real problem sizes, or raise max_states explicitly")
+            "an exhaustive test oracle — use place_frontier for real "
+            "problem sizes, or raise max_states explicitly")
 
 
-def place_exhaustive(ops: List[OperatorCost], resources: ResourcesLike,
-                     rate: float, objective: Optional[Objective] = None,
-                     *, max_states: int = 1_000_000) -> PipelinePlan:
-    """Oracle: try every assignment (exponential; tests only). Refuses
-    inputs whose ``pools**ops`` state count exceeds ``max_states``."""
-    objective = objective or Objective()
-    names = list(ClusterSpec.of(resources))
-    _check_state_count("place_exhaustive", len(names), len(ops), max_states)
-    best, best_score = None, float("inf")
-    for combo in itertools.product(names, repeat=len(ops)):
-        assign = {op.name: r for op, r in zip(ops, combo)}
-        plan = evaluate_plan(ops, assign, resources, rate)
-        s = objective.score(plan)
-        if s < best_score:
-            best, best_score = plan, s
-    return best
+def _edge_cloud_spec(resources: ResourcesLike) -> ClusterSpec:
+    """``resources`` as a :class:`ClusterSpec`, refused unless it holds
+    at least one pool of each kind (a frontier needs both sides)."""
+    spec = ClusterSpec.of(resources)
+    if not spec.edge_pools or not spec.cloud_pools:
+        kinds = sorted({r.kind for r in spec.values()})
+        raise ValueError(
+            "frontier placement needs at least one 'edge' and one 'cloud' "
+            f"pool; ClusterSpec has kinds {kinds or '(empty)'}")
+    return spec
 
 
 # ---------------------------------------------------------------------------
@@ -268,8 +186,8 @@ def frontier_plans(graph, resources: ResourcesLike, rate: float,
     frontier across the spec's edge pools, its complement across the
     cloud pods. For a one-edge/one-cloud spec each frontier has exactly
     one assignment, so this degenerates to the classic two-pool frontier
-    enumeration (and, for a linear :class:`~repro.core.pipeline.Pipeline`,
-    to :func:`prefix_cut_plans`).
+    enumeration (for a linear :class:`~repro.core.pipeline.Pipeline`,
+    one plan per prefix cut).
 
     ``codecs`` makes the uplink codec a searched plan dimension: each
     candidate name is attached to the spec's uplinks
@@ -281,18 +199,12 @@ def frontier_plans(graph, resources: ResourcesLike, rate: float,
     collapsed (see :func:`_codec_specs`), so score ties (e.g. a frontier
     with no uplink crossing) always resolve toward lossless. Ties within
     a frontier break canonically on the pool-index tuple — the same
-    order :func:`place_frontier_dp` uses.
+    order :func:`place_frontier` uses.
     """
-    spec = ClusterSpec.of(resources)
+    spec = _edge_cloud_spec(resources)
     objective = objective or Objective()
-    edges, clouds = spec.edge_pools, spec.cloud_pools
-    if not edges or not clouds:
-        kinds = sorted({r.kind for r in spec.values()})
-        raise ValueError(
-            "frontier placement needs at least one 'edge' and one 'cloud' "
-            f"pool; ClusterSpec has kinds {kinds or '(empty)'}")
-    e_names = [r.name for r in edges]
-    c_names = [r.name for r in clouds]
+    e_names = [r.name for r in spec.edge_pools]
+    c_names = [r.name for r in spec.cloud_pools]
     names = graph.names
     specs = _codec_specs(spec, codecs)
     pidx = {name: i for i, name in enumerate(spec)}
@@ -310,31 +222,14 @@ def frontier_plans(graph, resources: ResourcesLike, rate: float,
         yield frontier, best
 
 
-def _enumeration_plans(graph, n_edge: int, n_cloud: int,
-                       limit: float) -> Optional[float]:
-    """Number of (frontier x within-kind pool assignment) plans the
-    enumeration engine would price, or None as soon as the running total
-    passes ``limit`` (both the frontier walk and the arithmetic stop
-    early, so the estimate is cheap even on graphs with exponentially
-    many frontiers)."""
-    n = len(graph.names)
-    total = 0.0
-    for f in graph.frontiers():
-        k = len(f)
-        total += float(n_edge) ** k * float(n_cloud) ** (n - k)
-        if total > limit:
-            return None
-    return total
-
-
 def _all_cloud_fallback(graph, spec: ClusterSpec, rate: float,
                         objective: Objective,
                         codecs: Optional[Sequence[str]]
                         ) -> Tuple[PipelinePlan, FrozenSet[str]]:
     """The empty frontier on the first pod — always structurally valid;
     may still be infeasible under extreme rates (caller must check
-    ``.feasible``). Shared by both search engines so an infeasible
-    instance degrades identically whichever engine ran."""
+    ``.feasible``). Shared by the engine and its frontier oracle so an
+    infeasible instance degrades identically in both."""
     cloud = spec.cloud_pools[0]
     assign = {name: cloud.name for name in graph.names}
     fb, fb_key = None, None
@@ -345,65 +240,6 @@ def _all_cloud_fallback(graph, spec: ClusterSpec, rate: float,
         if fb is None or key < fb_key:
             fb, fb_key = plan, key
     return fb, frozenset()
-
-
-def place_frontier(graph, resources: ResourcesLike, rate: float,
-                   objective: Optional[Objective] = None,
-                   codecs: Optional[Sequence[str]] = None,
-                   *, method: str = "auto",
-                   enumerate_limit: int = 20_000,
-                   max_labels: int = 4096
-                   ) -> Tuple[PipelinePlan, FrozenSet[str]]:
-    """Best frontier-cut placement of an operator DAG over a
-    :class:`ClusterSpec` — multi-pool: each frontier side may split
-    across the pools of its kind, priced per crossing link with
-    codec-compressed bytes. With ``codecs`` the winning plan is the best
-    (frontier, pool-assignment, codec) triple and ``plan.uplink_codec``
-    names the codec it was priced under. Returns ``(plan, frontier)``
-    where ``frontier`` is the edge-resident op set (``plan.assignment``
-    holds the per-op pool detail).
-
-    ``method`` selects the engine: ``"enumerate"`` (the exhaustive
-    frontier x pool-product x codec walk), ``"dp"``
-    (:func:`place_frontier_dp` — cost-identical, polynomial on real
-    graphs), or ``"auto"`` (default): enumerate while the priced-plan
-    estimate stays within ``enumerate_limit``, DP above it — small
-    graphs keep the historical code path exactly, big graphs stop being
-    exponential."""
-    objective = objective or Objective()
-    spec = ClusterSpec.of(resources)
-    edges, clouds = spec.edge_pools, spec.cloud_pools
-    if not edges or not clouds:
-        kinds = sorted({r.kind for r in spec.values()})
-        raise ValueError(
-            "frontier placement needs at least one 'edge' and one 'cloud' "
-            f"pool; ClusterSpec has kinds {kinds or '(empty)'}")
-    if method not in ("auto", "enumerate", "dp"):
-        raise ValueError(f"method {method!r} not in ('auto', 'enumerate', "
-                         "'dp')")
-    if method == "auto":
-        n_codecs = max(len(codecs), 1) if codecs else 1
-        n_plans = _enumeration_plans(graph, len(edges), len(clouds),
-                                     limit=enumerate_limit / n_codecs)
-        method = "enumerate" if n_plans is not None else "dp"
-    if method == "dp":
-        return place_frontier_dp(graph, spec, rate, objective, codecs,
-                                 max_labels=max_labels)
-    specs = _codec_specs(spec, codecs)
-    rank_of = {cname: r for r, (cname, _) in enumerate(specs)}
-    pidx = {name: i for i, name in enumerate(spec)}
-    best, best_f, best_key = None, frozenset(), None
-    for frontier, plan in frontier_plans(graph, spec, rate, objective,
-                                         codecs=codecs):
-        key = (objective.score(plan), len(frontier),
-               rank_of.get(plan.uplink_codec, 0),
-               tuple(pidx[plan.assignment[n]] for n in graph.names))
-        if best is None or key < best_key:
-            best, best_f, best_key = plan, frontier, key
-    if best is None or not best.feasible:
-        best, best_f = _all_cloud_fallback(graph, spec, rate, objective,
-                                           codecs)
-    return best, best_f
 
 
 # ---------------------------------------------------------------------------
@@ -733,24 +569,32 @@ def _dp_pass(t: dict, rate: float, objective: Objective, incumbent: float,
 
 def _dp_final_key(lab, kinds, lw, ew, uw):
     """Selection key over completed labels — the same canonical order
-    the enumeration engine uses: (score, |frontier|, pool tuple)."""
+    the frontier oracle uses: (score, |frontier|, pool tuple)."""
     score = lw * lab[2] + ew * lab[1] * 1e-3 + uw * lab[3]
     n_edge = sum(1 for p in lab[0] if kinds[p] == "edge")
     return (score, n_edge, lab[0])
 
 
-def place_frontier_dp(graph, resources: ResourcesLike, rate: float,
-                      objective: Optional[Objective] = None,
-                      codecs: Optional[Sequence[str]] = None,
-                      *, max_labels: int = 4096,
-                      stats: Optional[dict] = None
-                      ) -> Tuple[PipelinePlan, FrozenSet[str]]:
-    """Polynomial placement over the frontier lattice: the label DP (see
-    module docstring) run once per codec candidate, warm-started by its
-    own greedy pass and by the best exact score of earlier candidates
-    (most-faithful-first, so ties resolve identically to the
-    enumeration). Returns ``(plan, frontier)`` exactly like
-    :func:`place_frontier`; the winning assignment is re-priced through
+def place_frontier(graph, resources: ResourcesLike, rate: float,
+                   objective: Optional[Objective] = None,
+                   codecs: Optional[Sequence[str]] = None,
+                   *, max_labels: int = 4096,
+                   stats: Optional[dict] = None
+                   ) -> Tuple[PipelinePlan, FrozenSet[str]]:
+    """Best frontier-cut placement of an operator graph over a
+    :class:`ClusterSpec` — multi-pool: each frontier side may split
+    across the pools of its kind, priced per crossing link with
+    codec-compressed bytes. With ``codecs`` the winning plan is the best
+    (frontier, pool-assignment, codec) triple and ``plan.uplink_codec``
+    names the codec it was priced under. Returns ``(plan, frontier)``
+    where ``frontier`` is the edge-resident op set (``plan.assignment``
+    holds the per-op pool detail).
+
+    The search is the label DP (see module docstring), run once per
+    codec candidate, warm-started by its own greedy pass and by the
+    best exact score of earlier candidates (most-faithful-first, so ties
+    resolve as :func:`place_frontier_oracle` resolves them). The winning
+    assignment is re-priced through
     :func:`~repro.core.costmodel.evaluate_graph_plan`, so the returned
     plan is the evaluator's own numbers, not the DP's bookkeeping.
 
@@ -763,18 +607,12 @@ def place_frontier_dp(graph, resources: ResourcesLike, rate: float,
     ``labels_expanded``, and ``truncated``, which is True iff any width
     or per-bucket cap clipped an exact sweep, i.e. iff optimality is no
     longer certified). Runtime is O(ops x max_labels x pools) either
-    way — the polynomial envelope the exponential enumeration lacked."""
-    spec = ClusterSpec.of(resources)
+    way."""
+    spec = _edge_cloud_spec(resources)
     objective = objective or Objective()
-    edges, clouds = spec.edge_pools, spec.cloud_pools
-    if not edges or not clouds:
-        kinds = sorted({r.kind for r in spec.values()})
-        raise ValueError(
-            "frontier placement needs at least one 'edge' and one 'cloud' "
-            f"pool; ClusterSpec has kinds {kinds or '(empty)'}")
     lw, ew, uw = (objective.latency_weight, objective.energy_weight,
                   objective.uplink_weight)
-    edge_names = {r.name for r in edges}
+    edge_names = {r.name for r in spec.edge_pools}
     pidx = {name: i for i, name in enumerate(spec)}
     agg = {"labels_peak": 0, "labels_expanded": 0, "truncated": False}
     best, best_f, best_key = None, frozenset(), None
@@ -815,6 +653,34 @@ def place_frontier_dp(graph, resources: ResourcesLike, rate: float,
     return best, best_f
 
 
+def place_frontier_oracle(graph, resources: ResourcesLike, rate: float,
+                          objective: Optional[Objective] = None,
+                          codecs: Optional[Sequence[str]] = None
+                          ) -> Tuple[PipelinePlan, FrozenSet[str]]:
+    """Oracle for :func:`place_frontier`: the canonical pick over the
+    exhaustive frontier x pool-product x codec walk of
+    :func:`frontier_plans` (exponential; tests and the benchmark harness
+    only). It returns the same ``(plan, frontier)`` the DP must return,
+    tie direction included."""
+    spec = _edge_cloud_spec(resources)
+    objective = objective or Objective()
+    specs = _codec_specs(spec, codecs)
+    rank_of = {cname: r for r, (cname, _) in enumerate(specs)}
+    pidx = {name: i for i, name in enumerate(spec)}
+    best, best_f, best_key = None, frozenset(), None
+    for frontier, plan in frontier_plans(graph, spec, rate, objective,
+                                         codecs=codecs):
+        key = (objective.score(plan), len(frontier),
+               rank_of.get(plan.uplink_codec, 0),
+               tuple(pidx[plan.assignment[n]] for n in graph.names))
+        if best is None or key < best_key:
+            best, best_f, best_key = plan, frontier, key
+    if best is None or not best.feasible:
+        best, best_f = _all_cloud_fallback(graph, spec, rate, objective,
+                                           codecs)
+    return best, best_f
+
+
 def place_graph_exhaustive(graph, resources: ResourcesLike,
                            rate: float,
                            objective: Optional[Objective] = None,
@@ -823,9 +689,8 @@ def place_graph_exhaustive(graph, resources: ResourcesLike,
     pool of the spec — including non-downward-closed and cross-kind-
     scrambled ones (exponential; tests and the benchmark harness only).
     With a multi-pool ClusterSpec this is the multi-pool oracle
-    :func:`place_frontier` and :func:`place_frontier_dp` are checked
-    against. Refuses inputs whose ``pools**ops`` state count exceeds
-    ``max_states``."""
+    :func:`place_frontier` is checked against. Refuses inputs whose
+    ``pools**ops`` state count exceeds ``max_states``."""
     objective = objective or Objective()
     spec = ClusterSpec.of(resources)
     rnames = list(spec)
@@ -845,18 +710,23 @@ def place_graph_exhaustive(graph, resources: ResourcesLike,
 # Standard S2CE pipeline stage costs
 # ---------------------------------------------------------------------------
 
+def _pass_through(state, batch):
+    return state, batch
+
+
 def standard_pipeline(dim: int = 32, model_flops_per_event: float = 2e6,
-                      sample_rate: float = 0.25) -> List[OperatorCost]:
+                      sample_rate: float = 0.25) -> Pipeline:
     """ingest -> preprocess -> sample/sketch -> pre-model -> full train.
 
-    A synthetic DL-payload cost-list *exemplar* (placement oracle tests,
-    S3 benchmark, edge_cloud example). Executable jobs should not use
-    this: build a :class:`repro.core.pipeline.Pipeline` and price it via
-    ``Pipeline.costs()`` so the optimizer and the executor consume the
-    same op list.
+    A synthetic DL-payload cost *exemplar* (placement oracle tests, S3
+    benchmark, edge_cloud example): a :class:`Pipeline` whose ops carry
+    these stage costs and pass the batch through unchanged, so it is
+    planned like any other graph. Executable jobs build their
+    :class:`Pipeline` from real ops instead, so the optimizer and the
+    executor consume the same op list.
     """
     ev = 4.0 * dim
-    return [
+    costs = [
         OperatorCost("ingest", flops_per_event=10 * dim,
                      bytes_per_event=2 * ev, out_bytes_per_event=ev),
         OperatorCost("preprocess", flops_per_event=50 * dim,
@@ -871,3 +741,4 @@ def standard_pipeline(dim: int = 32, model_flops_per_event: float = 2e6,
                      bytes_per_event=20 * ev,
                      out_bytes_per_event=64, edge_capable=False),
     ]
+    return Pipeline([Op(c.name, _pass_through, c) for c in costs])

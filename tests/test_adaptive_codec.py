@@ -126,7 +126,7 @@ def test_observe_before_initial_plan_takes_initial_lazily():
     """Regression: observe() before initial_plan() raised IndexError on
     history[-1]; it now takes the initial plan lazily."""
     g = pl.fanout_stream_graph(dim=16)
-    ctl = OffloadController(g.costs(), cm.ClusterSpec.edge_cloud(), graph=g)
+    ctl = OffloadController(g, cm.ClusterSpec.edge_cloud())
     d = ctl.observe(step=7, rate=1e4)
     assert d.reason == "initial"
     assert d.step == 7
@@ -260,7 +260,7 @@ def test_codec_search_ties_resolve_toward_first_candidate():
 
 def _ramp_controller(**kw):
     g = _pipe(dim=8)
-    return OffloadController(g.costs(), cm.ClusterSpec.edge_cloud(), graph=g,
+    return OffloadController(g, cm.ClusterSpec.edge_cloud(),
                              codec="topk_int8_ef", sla_spec=LOOSE, **kw)
 
 
@@ -304,7 +304,7 @@ def test_fixed_codec_controller_unchanged_without_sla_spec():
     """No sla_spec -> the historical fixed-codec behavior: the codec is
     pinned no matter what the rate does."""
     g = _pipe(dim=8)
-    ctl = OffloadController(g.costs(), cm.ClusterSpec.edge_cloud(), graph=g,
+    ctl = OffloadController(g, cm.ClusterSpec.edge_cloud(),
                             codec="int8_ef", cooldown=1)
     assert not ctl._adaptive
     ctl.initial_plan(1e4)
@@ -322,7 +322,7 @@ def test_user_declared_link_codec_survives_adaptive_replans():
         links=[cm.Link("edge", "cloud", bw=1e9, latency=20e-3,
                        codec="int8_ef")])
     g = _pipe(dim=8)
-    ctl = OffloadController(g.costs(), spec, graph=g, codec="int8_ef",
+    ctl = OffloadController(g, spec, codec="int8_ef",
                             sla_spec=SLA(max_latency_s=1e3, error_budget=0.1),
                             cooldown=1, codec_cooldown=1)
     ctl.initial_plan(1e4)

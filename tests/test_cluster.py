@@ -1,7 +1,7 @@
 """ClusterSpec topology API: multi-pool placement vs the exhaustive
 oracle, link-attached uplink codecs (pricing + SLA admission + tested
 error bounds under composition), critical-path DAG latency, and parity
-of two-pool plans through the deprecated flat-dict shim."""
+of two-pool plans between a flat resource dict and the explicit spec."""
 
 import numpy as np
 import pytest
@@ -14,7 +14,7 @@ from repro.core import pipeline as pl
 from repro.core.offload import OffloadController
 from repro.core.orchestrator import Orchestrator, StreamJob
 from repro.core.placement import (Objective, frontier_plans, place_frontier,
-                                  place_graph_exhaustive, prefix_cut_plans)
+                                  place_graph_exhaustive)
 from repro.core.sla import SLA, pick_codec
 from repro.streams.generators import HyperplaneStream
 
@@ -249,18 +249,23 @@ def test_backhaul_still_infeasible_multipool():
 # ---------------------------------------------------------------------------
 
 def test_chain_latency_is_the_per_op_sum():
-    """A chain has one path, so critical-path pricing reproduces the
-    historical per-op sum exactly (the PR 2/3 parity anchor)."""
+    """A chain has one path, so critical-path pricing is the per-op sum:
+    every op's compute latency plus one edge->cloud hop wherever the
+    stream crosses (the source sits on the edge pool)."""
     pipe = pl.standard_stream_pipeline(dim=16)
-    res = {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD}
-    for k, lin in prefix_cut_plans(pipe.costs(), res, 1e4):
-        frontier = frozenset(pipe.names[:k])
+    spec = cm.ClusterSpec.edge_cloud()
+    hop = spec.link("edge", "cloud").latency
+    n = len(pipe.names)
+    for k in range(n + 1):
+        assign = {nm: ("edge" if i < k else "cloud")
+                  for i, nm in enumerate(pipe.names)}
         g = cm.evaluate_graph_plan(
-            pipe.costs(), pipe.flow_edges,
-            {n: ("edge" if n in frontier else "cloud") for n in pipe.names},
-            res, 1e4, source_consumers=pipe.source_consumers,
+            pipe.costs(), pipe.flow_edges, assign, spec, 1e4,
+            source_consumers=pipe.source_consumers,
             source_bytes=pipe.source_bytes_per_event)
-        assert g.latency_s == pytest.approx(lin.latency_s)
+        want = sum(c.flops_per_event / spec[assign[c.name]].total_flops
+                   for c in pipe.costs()) + (hop if k < n else 0.0)
+        assert g.latency_s == pytest.approx(want)
 
 
 def test_parallel_branches_overlap_on_the_critical_path():
@@ -424,7 +429,7 @@ def test_composed_codec_beats_its_parts_on_wire_bytes():
 
 def test_offload_controller_plan_identity_includes_pools_and_codec():
     g = pl.fanout_stream_graph(dim=16)
-    ctl = OffloadController(g.costs(), multipool_spec(), graph=g,
+    ctl = OffloadController(g, multipool_spec(),
                             codec="int8_ef", cooldown=1)
     d0 = ctl.initial_plan(1e3)
     assert d0.codec == "int8_ef"
@@ -529,7 +534,7 @@ def _energy_spec(epb: float, codec: str = "identity") -> cm.ClusterSpec:
 def test_link_energy_per_byte_priced_into_energy_aggregate():
     """Every crossing adds wire_bytes * rate * energy_per_byte watts:
     the delta vs an energy-free link is exactly the summed link-byte
-    rate times the joules-per-byte, in both evaluators."""
+    rate times the joules-per-byte."""
     pipe = pl.standard_stream_pipeline(dim=8)
     rate, epb = 1e4, 3e-7
     assign = {n: ("edge" if i < 3 else "cloud")
@@ -549,10 +554,6 @@ def test_link_energy_per_byte_priced_into_energy_aggregate():
                    for k, u in g1.link_utilization.items())
         assert want > 0.0
         assert g1.energy_w - g0.energy_w == pytest.approx(want)
-        # linear evaluator prices the same crossings identically
-        l0 = cm.evaluate_plan(pipe.costs(), assign, zero, rate)
-        l1 = cm.evaluate_plan(pipe.costs(), assign, priced, rate)
-        assert l1.energy_w - l0.energy_w == pytest.approx(want)
         # everything else is untouched by the energy term
         assert g1.latency_s == g0.latency_s
         assert g1.link_utilization == g0.link_utilization

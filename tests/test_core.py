@@ -7,8 +7,8 @@ import pytest
 from repro.core import costmodel as cm
 from repro.core.offload import OffloadController
 from repro.core.orchestrator import Orchestrator, StreamJob
-from repro.core.placement import (Objective, place, place_exhaustive,
-                                  standard_pipeline)
+from repro.core.placement import (Objective, place_frontier,
+                                  place_graph_exhaustive, standard_pipeline)
 from repro.core.sla import SLA, SLATracker
 from repro.streams.generators import DriftSpec, HyperplaneStream
 
@@ -24,32 +24,31 @@ def test_stage_time_roofline_max():
 
 @pytest.mark.parametrize("rate", [1e2, 1e4, 1e6])
 def test_prefix_cut_matches_exhaustive_oracle(rate):
-    ops = standard_pipeline(dim=16)
+    pipe = standard_pipeline(dim=16)
     obj = Objective()
-    best, _ = place(ops, RES, rate, obj)
-    oracle = place_exhaustive(ops, RES, rate, obj)
+    best, frontier = place_frontier(pipe, RES, rate, obj)
+    oracle = place_graph_exhaustive(pipe, RES, rate, obj)
+    assert frontier == frozenset(pipe.names[:len(frontier)])
     assert obj.score(best) <= obj.score(oracle) * 1.0001, (
-        "prefix-cut placement must match the exhaustive oracle on linear "
-        "pipelines")
+        "placement of a linear pipeline must match the exhaustive oracle")
 
 
 def test_dl_train_never_on_edge():
-    ops = standard_pipeline(dim=16)
+    pipe = standard_pipeline(dim=16)
     for rate in (1e2, 1e5):
-        plan, _ = place(ops, RES, rate)
+        plan, _ = place_frontier(pipe, RES, rate)
         assert plan.assignment["dl_train"] == "cloud"
 
 
 def test_high_rate_pushes_work_to_cloud():
-    ops = standard_pipeline(dim=64)
-    _, cut_lo = place(ops, RES, 1e3)
-    _, cut_hi = place(ops, RES, 5e6)
-    assert cut_hi <= cut_lo, "rising rate must move stages off the edge"
+    pipe = standard_pipeline(dim=64)
+    _, edge_lo = place_frontier(pipe, RES, 1e3)
+    _, edge_hi = place_frontier(pipe, RES, 5e6)
+    assert edge_hi <= edge_lo, "rising rate must move stages off the edge"
 
 
 def test_offload_hysteresis_no_thrash():
-    ops = standard_pipeline(dim=32)
-    ctl = OffloadController(ops, RES, cooldown=3)
+    ctl = OffloadController(standard_pipeline(dim=32), RES, cooldown=3)
     ctl.initial_plan(1e4)
     # oscillate +-10% (inside the 1.3x band): no migrations
     for step in range(1, 30):
@@ -59,8 +58,7 @@ def test_offload_hysteresis_no_thrash():
 
 
 def test_offload_reacts_to_burst():
-    ops = standard_pipeline(dim=64)
-    ctl = OffloadController(ops, RES, cooldown=1)
+    ctl = OffloadController(standard_pipeline(dim=64), RES, cooldown=1)
     d0 = ctl.initial_plan(1e3)
     d1 = ctl.observe(1, 1e7)       # big burst
     assert d1.cut <= d0.cut

@@ -20,7 +20,8 @@ from repro.core.codecs import (DEFAULT_CODECS, KV_CODECS, get_codec,
                                kv_latent_codec)
 from repro.core.offload import OffloadController
 from repro.core.pipeline import OpGraph
-from repro.core.placement import Objective, _graph_plan, place_frontier
+from repro.core.placement import (Objective, _graph_plan, place_frontier,
+                                  place_frontier_oracle)
 from repro.launch.roofline import dl_operator_cost
 from repro.models import model_zoo as zoo
 from repro.serve.engine import Request, ServeEngine
@@ -205,7 +206,7 @@ def test_dp_excludes_edge_pool_with_insufficient_mem_cap():
     kv_state = g.op("decode").cost.state_bytes
     tiny = serving_spec(edge_mem=kv_state / 2)
     assert kv_state > tiny.pools["edge0"].mem_cap
-    plan, frontier = place_frontier(g, tiny, 1e3, Objective(), method="dp")
+    plan, frontier = place_frontier(g, tiny, 1e3, Objective())
     assert plan.feasible
     assert plan.assignment == {"prefill": "cloud0", "decode": "cloud0"}
     assert frontier == frozenset()
@@ -221,9 +222,9 @@ def test_dp_selects_cloud_prefill_edge_decode_under_pod_saturation():
     g = serving_graph_for_placement()
     spec = serving_spec()
     obj = Objective()
-    for method in ("dp", "enumerate"):
-        plan, frontier = place_frontier(g, spec, 3e3, obj, method=method)
-        assert plan.feasible, method
+    for place in (place_frontier, place_frontier_oracle):
+        plan, frontier = place(g, spec, 3e3, obj)
+        assert plan.feasible, place.__name__
         assert plan.assignment == {"prefill": "cloud0", "decode": "edge0"}
         assert frontier == frozenset({"decode"})
     # the KV crossing is priced on the downlink, not free
@@ -353,8 +354,7 @@ def test_migration_cost_prices_moved_state_per_link():
 def test_replan_decision_carries_priced_migration():
     g = serving_graph_for_placement()
     spec = serving_spec()
-    ctl = OffloadController(g.costs(), spec, Objective(), graph=g,
-                            cooldown=0)
+    ctl = OffloadController(g, spec, Objective(), cooldown=0)
     ctl.initial_plan(1e3)
     assert ctl.history[-1].migration.moves == ()
     d = ctl.replan(1, 3e3)

@@ -1,7 +1,6 @@
 """The shipped example set is the public face of the API: every script
-must run green, and none may route through the deprecated
-``edge_cloud_pools`` shim (its DeprecationWarning would land in every
-new user's first session). Scripts run as real subprocesses with
+must run green, and none may emit a DeprecationWarning (it would land in
+every new user's first session). Scripts run as real subprocesses with
 warnings forced on, so a regression anywhere in the import graph — not
 just in the example text — trips this."""
 
@@ -38,7 +37,6 @@ def test_examples_run_clean_of_deprecated_shims():
         out, _ = proc.communicate(timeout=600)
         if proc.returncode != 0:
             failures.append(f"{name} exited {proc.returncode}:\n{out}")
-        if "edge_cloud_pools" in out:
-            failures.append(f"{name} touched the deprecated "
-                            f"edge_cloud_pools shim:\n{out}")
+        if "DeprecationWarning" in out:
+            failures.append(f"{name} emitted a DeprecationWarning:\n{out}")
     assert not failures, "\n\n".join(failures)

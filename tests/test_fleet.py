@@ -32,8 +32,8 @@ def make_controller(spec, sla=LOOSE, dim=8, **kw) -> OffloadController:
     # Orchestrator does — calibrated link sizes then transfer between
     # scheduler-level and orchestrator-level tests
     kw.setdefault("codec", pick_codec(sla).name)
-    return OffloadController(pl.standard_stream_pipeline(dim=dim).costs(),
-                             spec, sla_spec=sla, **kw)
+    return OffloadController(pl.standard_stream_pipeline(dim=dim), spec,
+                             sla_spec=sla, **kw)
 
 
 def _batches(n, dim=8, n_per=32, seed=0):
@@ -75,11 +75,11 @@ def test_residual_fully_reserved_pool_prices_infeasible_not_div0():
     r = spec.residual(pool_load={"edge": 1.0})
     # epsilon share, not zero: no div-by-zero, but hopelessly slow
     assert 0.0 < r["edge"].flops <= cm.EDGE_NODE.flops * 1e-6
-    plan = cm.evaluate_plan(pl.standard_stream_pipeline(dim=8).costs(),
-                            {op.name: "edge" for op in
-                             pl.standard_stream_pipeline(dim=8).costs()
-                             if True},
-                            r, rate=1e4)
+    pipe = pl.standard_stream_pipeline(dim=8)
+    plan = cm.evaluate_graph_plan(
+        pipe.costs(), pipe.flow_edges, {n: "edge" for n in pipe.names}, r,
+        rate=1e4, source_consumers=pipe.source_consumers,
+        source_bytes=pipe.source_bytes_per_event)
     assert not plan.feasible
 
 

@@ -1,7 +1,7 @@
 """Operator-DAG IR: channel-inferred dependencies, frontier (downward-
 closed) cuts, per-crossing-edge pricing, frontier placement vs the
-exhaustive oracle, linear-parity with the prefix-cut path, and the
-orchestrator running a fan-out/rejoin graph end to end."""
+exhaustive oracle, a linear Pipeline planned as its best prefix cut,
+and the orchestrator running a fan-out/rejoin graph end to end."""
 
 import numpy as np
 import pytest
@@ -13,9 +13,8 @@ from repro.core import costmodel as cm
 from repro.core import pipeline as pl
 from repro.core.offload import OffloadController
 from repro.core.orchestrator import Orchestrator, StreamJob
-from repro.core.placement import (Objective, frontier_plans, place,
-                                  place_frontier, place_graph_exhaustive,
-                                  prefix_cut_plans)
+from repro.core.placement import (Objective, frontier_plans, place_frontier,
+                                  place_graph_exhaustive)
 from repro.streams.generators import HyperplaneStream
 
 RES = {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD}
@@ -103,9 +102,9 @@ def test_frontier_enumeration_matches_bruteforce():
 def test_pipeline_frontiers_are_exactly_the_prefixes():
     pipe = pl.standard_stream_pipeline(dim=8)
     fronts = list(pipe.frontiers())
-    assert len(fronts) == pipe.n_cuts
+    assert len(fronts) == len(pipe.names) + 1
     assert set(fronts) == {frozenset(pipe.names[:k])
-                           for k in range(pipe.n_cuts)}
+                           for k in range(len(pipe.names) + 1)}
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +171,7 @@ def test_fuse_xla_segments_match_op_mode_allclose(linear):
     if linear:
         ref = pl.standard_stream_pipeline(dim=8)
         xla = pl.Pipeline(ref.ops, fuse="xla")
-        cuts = (0, 2, len(ref.ops))
+        cuts = tuple(frozenset(ref.names[:k]) for k in (0, 2, len(ref.ops)))
     else:
         ref = pl.fanout_stream_graph(dim=8)
         xla = pl.OpGraph(ref.ops, fuse="xla")
@@ -231,13 +230,21 @@ def test_frontier_plans_price_each_crossing_edge():
 
 
 def test_linear_pipeline_plans_unchanged_vs_prefix_cut():
-    """PR 2 parity: a linear Pipeline priced/partitioned through the new
-    frontier machinery must produce exactly the prefix-cut plans, and
-    place() must keep returning the same chosen plan and cost."""
+    """A linear Pipeline's frontiers are its prefix cuts, and the DP
+    places it at the best one: the plan and cost of pricing every prefix
+    through the graph evaluator and keeping the cheapest (ties to the
+    smaller cut)."""
     pipe = pl.standard_stream_pipeline(dim=16)
-    ops = pipe.costs()
+    obj = Objective()
     for rate in (1e2, 1e4, 3e6):
-        by_cut = {k: plan for k, plan in prefix_cut_plans(ops, RES, rate)}
+        by_cut = {}
+        for k in range(len(pipe.names) + 1):
+            assign = {n: ("edge" if i < k else "cloud")
+                      for i, n in enumerate(pipe.names)}
+            by_cut[k] = cm.evaluate_graph_plan(
+                pipe.costs(), pipe.flow_edges, assign, RES, rate,
+                source_consumers=pipe.source_consumers,
+                source_bytes=pipe.source_bytes_per_event)
         for frontier, plan in frontier_plans(pipe, RES, rate):
             ref = by_cut[len(frontier)]
             assert frontier == frozenset(pipe.names[:len(frontier)])
@@ -247,12 +254,12 @@ def test_linear_pipeline_plans_unchanged_vs_prefix_cut():
                 ref.uplink_utilization)
             assert plan.energy_w == pytest.approx(ref.energy_w)
             assert plan.feasible == ref.feasible
-        lin_plan, lin_cut = place(ops, RES, rate)
+        best_cut = min(by_cut, key=lambda k: (obj.score(by_cut[k]), k))
         g_plan, g_frontier = place_frontier(pipe, RES, rate)
-        assert len(g_frontier) == lin_cut
-        assert g_plan.assignment == lin_plan.assignment
-        obj = Objective()
-        assert obj.score(g_plan) == pytest.approx(obj.score(lin_plan))
+        assert len(g_frontier) == best_cut
+        assert g_plan.assignment == by_cut[best_cut].assignment
+        assert obj.score(g_plan) == pytest.approx(
+            obj.score(by_cut[best_cut]))
 
 
 def test_backhaul_assignments_are_infeasible():
@@ -273,7 +280,7 @@ def test_backhaul_assignments_are_infeasible():
 
 def test_graph_offload_migrates_frontier_on_burst():
     g = pl.fanout_stream_graph(dim=16)
-    ctl = OffloadController(g.costs(), RES, graph=g, cooldown=1)
+    ctl = OffloadController(g, RES, cooldown=1)
     d0 = ctl.initial_plan(1e3)
     assert d0.frontier == ctl.frontier and d0.cut == len(d0.frontier)
     assert len(d0.frontier) > 0, "cheap rate keeps work on the edge"
@@ -285,7 +292,7 @@ def test_graph_offload_migrates_frontier_on_burst():
 
 def test_graph_offload_hysteresis_holds_inside_band():
     g = pl.fanout_stream_graph(dim=16)
-    ctl = OffloadController(g.costs(), RES, graph=g, cooldown=3)
+    ctl = OffloadController(g, RES, cooldown=3)
     ctl.initial_plan(1e4)
     for step in range(1, 30):
         d = ctl.observe(step, 1e4 * (1.1 if step % 2 else 0.9))

@@ -7,7 +7,6 @@ replans, queue re-admission on joins) — plus the differential contract:
 a directory nobody mutates runs bitwise identically to a static
 ClusterSpec."""
 
-import warnings
 
 import pytest
 
@@ -18,8 +17,7 @@ from repro.core.membership import (LINK_UPDATE, POOL_FAILED, POOL_JOINED,
                                    POOL_LEFT, Locality, MembershipDirectory)
 from repro.core.offload import OffloadController
 from repro.core.orchestrator import Orchestrator, StreamJob
-from repro.core.placement import (edge_cloud_pools, place_frontier,
-                                  stale_pools)
+from repro.core.placement import place_frontier, stale_pools
 from repro.core.sla import SLA, pick_codec
 from repro.streams.generators import HyperplaneStream
 
@@ -46,8 +44,8 @@ def _batches(n, dim=8, n_per=32, seed=0):
 
 def make_controller(spec, sla=LOOSE, dim=8, **kw) -> OffloadController:
     kw.setdefault("codec", pick_codec(sla).name)
-    return OffloadController(pl.standard_stream_pipeline(dim=dim).costs(),
-                             spec, sla_spec=sla, **kw)
+    return OffloadController(pl.standard_stream_pipeline(dim=dim), spec,
+                             sla_spec=sla, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -239,30 +237,6 @@ def test_link_unknown_pool_raises_valueerror_naming_pools():
         spec.link("gone", "cloud")
 
 
-def test_edge_cloud_pools_shim_warns_once():
-    """Satellite: the two-pool shim emits a real DeprecationWarning."""
-    with pytest.warns(DeprecationWarning, match="two-pool shim"):
-        e, c = edge_cloud_pools(two_pool_spec())
-    assert e.name == "edge" and c.name == "cloud"
-    # the default "once per location" filter dedups repeat calls
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("default")
-        for _ in range(3):
-            edge_cloud_pools(two_pool_spec())
-    assert len([w for w in rec
-                if issubclass(w.category, DeprecationWarning)]) == 1
-
-
-def test_prefix_cut_engine_does_not_warn():
-    """place() IS the two-pool engine: its internal collapse must not
-    spam a deprecation warning on every replan."""
-    from repro.core.placement import place
-    ops = pl.standard_stream_pipeline(dim=8).costs()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        place(ops, two_pool_spec(), rate=1e4)
-
-
 # ---------------------------------------------------------------------------
 # stale-plan guard (placement.stale_pools + controller)
 # ---------------------------------------------------------------------------
@@ -282,8 +256,7 @@ def test_controller_cannot_hold_a_stale_plan():
         pools=[cm.EDGE_NODE, edge_b(), cm.CLOUD_POD],
         links=[cm.Link("edge", "cloud", bw=2e6, latency=20e-3),
                cm.Link("edge_b", "cloud", bw=8e6, latency=5e-3)])
-    c = OffloadController(pl.fanout_stream_graph(8).costs(), big,
-                          graph=pl.fanout_stream_graph(8), sla_spec=LOOSE,
+    c = OffloadController(pl.fanout_stream_graph(8), big, sla_spec=LOOSE,
                           codec=pick_codec(LOOSE).name, cooldown=10**6)
     c.initial_plan(1e4, step=0)
     assert "edge_b" in set(c.assignment.values())

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from repro.core import costmodel as cm
 from repro.core import pipeline as pl
 from repro.core.orchestrator import Orchestrator, StreamJob
-from repro.core.placement import _first_edge_cloud, place
+from repro.core.placement import place_frontier, place_graph_exhaustive
 from repro.streams.events import StreamBatch
 from repro.streams.fusion import WindowJoin
 from repro.streams.generators import DriftSpec, HyperplaneStream
@@ -30,7 +30,7 @@ def _run_cut(pipe, data, cut):
     for b in data:
         bd = {k: jnp.asarray(v) for k, v in b.data.items()}
         bd["rng"] = rng
-        states, out = pipe.run(states, bd, cut)
+        states, out = pipe.run(states, bd, pipe.names[:cut])
         rng = out["rng"]
         outs.append({k: np.asarray(v) for k, v in out.items() if k != "rng"})
     return states, outs
@@ -54,8 +54,8 @@ def test_pipeline_rejects_bad_construction():
         pl.Pipeline([op, op])
     with pytest.raises(ValueError):
         pl.Pipeline([op], fuse="welded")
-    with pytest.raises(ValueError):
-        pl.Pipeline([op]).run({}, {}, cut=5)
+    with pytest.raises(ValueError, match="unknown ops"):
+        pl.Pipeline([op]).run({}, {}, frontier={"ghost"})
 
 
 def test_costs_and_executor_share_the_op_list():
@@ -68,7 +68,7 @@ def test_every_cut_matches_unpartitioned_reference():
     pipe = pl.standard_stream_pipeline(dim=8, sample_rate=0.7)
     data = _batches(4)
     ref_states, ref_outs = _run_cut(pipe, data, 0)
-    for cut in range(1, pipe.n_cuts):
+    for cut in range(1, len(pipe.names) + 1):
         states, outs = _run_cut(pipe, data, cut)
         for a, b in zip(ref_outs, outs):
             assert set(a) == set(b)
@@ -88,37 +88,113 @@ def test_compile_cache_hit_on_cut_revisit():
     for b, cut in zip(data, (3, 2, 3)):       # migrate away and back
         bd = {k: jnp.asarray(v) for k, v in b.data.items()}
         bd["rng"] = rng
-        states, out = pipe.run(states, bd, cut)
+        states, out = pipe.run(states, bd, pipe.names[:cut])
         rng = out["rng"]
     compiles_after_first_visit = pipe.compiles
     assert pipe.cache_hits >= 2                # cut=3 revisit was free
     bd = {k: jnp.asarray(v) for k, v in data[0].data.items()}
     bd["rng"] = rng
-    pipe.run(states, bd, 3)
+    pipe.run(states, bd, pipe.names[:3])
     assert pipe.compiles == compiles_after_first_visit
 
 
+def test_pipeline_run_rejects_non_prefix_frontier():
+    """The chain's frontiers are its prefixes: a set that skips an op's
+    predecessor is not downward-closed and must not run."""
+    pipe = pl.standard_stream_pipeline(dim=8)
+    bd = {k: jnp.asarray(v) for k, v in _batches(1)[0].data.items()}
+    bd["rng"] = jax.random.PRNGKey(0)
+    with pytest.raises(ValueError, match="not downward-closed"):
+        pipe.run(pipe.init_states(), bd, {"normalize", "sample"})
+    with pytest.raises(ValueError, match="not downward-closed"):
+        pipe.check_frontier({"sketch"})
+    assert set(pipe.frontiers()) == {frozenset(pipe.names[:k])
+                                     for k in range(len(pipe.names) + 1)}
+
+
 # ---------------------------------------------------------------------------
-# placement pools (satellite: clear errors instead of StopIteration)
+# placement: a Pipeline is planned like any OpGraph
 # ---------------------------------------------------------------------------
+
+def _best_prefix(pipe, spec, rate, obj):
+    """The best prefix cut ``k`` (ops[:k] on the edge pool, the rest on
+    the cloud pod) by pricing every prefix through the graph evaluator;
+    ties go to the smaller cut."""
+    edge, cloud = spec.edge_pools[0].name, spec.cloud_pools[0].name
+    best = None
+    for k in range(len(pipe.names) + 1):
+        assign = {n: edge if i < k else cloud
+                  for i, n in enumerate(pipe.names)}
+        plan = cm.evaluate_graph_plan(
+            pipe.costs(), pipe.flow_edges, assign, spec, rate,
+            source_consumers=pipe.source_consumers,
+            source_bytes=pipe.source_bytes_per_event)
+        if best is None or obj.score(plan) < obj.score(best[1]):
+            best = (k, plan)
+    return best
+
 
 def test_placement_requires_both_pool_kinds():
-    ops = pl.standard_stream_pipeline(dim=8).costs()
+    pipe = pl.standard_stream_pipeline(dim=8)
     for bad in ({}, {"edge": cm.EDGE_NODE}, {"cloud": cm.CLOUD_POD}):
         with pytest.raises(ValueError, match="edge.*cloud|cloud.*edge"):
-            place(ops, bad, 1e4)
+            place_frontier(pipe, bad, 1e4)
 
 
-def test_placement_takes_first_pool_of_each_kind():
-    edge2 = cm.Resource("edge2", "edge", chips=2)
-    cloud2 = cm.Resource("cloud2", "cloud", chips=2)
-    res = {"edge": cm.EDGE_NODE, "edge2": edge2,
-           "cloud": cm.CLOUD_POD, "cloud2": cloud2}
-    # the warning-free collapse rule behind the deprecated shim
-    e, c = _first_edge_cloud(res)
-    assert (e.name, c.name) == ("edge", "cloud")
-    plan, _ = place(pl.standard_stream_pipeline(dim=8).costs(), res, 1e4)
-    assert set(plan.assignment.values()) <= {"edge", "cloud"}
+def _two_by_two_spec():
+    edge2 = cm.Resource("edge2", "edge", chips=2, flops=4e12, mem_bw=100e9,
+                        mem_cap=8e9, net_bw=2e9, net_latency=10e-3,
+                        energy_w=20.0)
+    cloud2 = cm.Resource("cloud2", "cloud", chips=16, net_latency=0.5e-3,
+                         energy_w=220.0)
+    return cm.ClusterSpec(
+        pools=[cm.EDGE_NODE, edge2, cm.CLOUD_POD, cloud2],
+        links=[cm.Link("edge2", "cloud2", bw=2e9, latency=5e-3),
+               cm.Link("edge", "edge2", bw=2e9, latency=2e-3)])
+
+
+@pytest.mark.parametrize("rate", [1e2, 1e4, 1e6])
+def test_pipeline_places_across_every_pool(rate):
+    """A Pipeline job on a 2-edge/2-cloud spec is placed across every
+    pool (not the first of each kind): its plan scores as well as the
+    all-assignments oracle, and never worse than the best plan confined
+    to the first edge pool and the first cloud pod."""
+    orch = Orchestrator(StreamJob("pools", dim=8,
+                                  cluster=_two_by_two_spec()))
+    pipe, obj = orch.pipeline, orch.job.objective
+    d = orch.controller.initial_plan(rate)
+    oracle = place_graph_exhaustive(pipe, orch.cluster, rate, obj)
+    assert obj.score(d.plan) <= obj.score(oracle) * 1.0001
+    assert d.frontier == frozenset(pipe.names[:d.cut])
+    first = cm.ClusterSpec(pools=[cm.EDGE_NODE, cm.CLOUD_POD])
+    _, collapsed = _best_prefix(pipe, first, rate, obj)
+    assert obj.score(d.plan) <= obj.score(collapsed) * 1.0001
+    # the second pools are the stronger ones here: the plan uses them
+    assert set(d.assignment.values()) - {"edge", "cloud"}
+
+
+@pytest.mark.parametrize("rate", [1e3, 1e5, 1e7])
+def test_orchestrator_default_pipeline_plans_best_prefix(rate):
+    """A default StreamJob (the standard chain on the one-edge/one-cloud
+    spec) takes the best prefix cut as its initial plan."""
+    orch = Orchestrator(StreamJob("default", dim=8))
+    d = orch.controller.initial_plan(rate)
+    k, plan = _best_prefix(orch.pipeline, orch.cluster, rate,
+                           orch.job.objective)
+    assert d.frontier == frozenset(orch.pipeline.names[:k])
+    assert d.cut == k
+    assert d.assignment == plan.assignment
+
+
+def test_stream_job_defaults_to_edge_cloud_spec():
+    """With no cluster and no membership directory the job runs on
+    ClusterSpec.edge_cloud(): one EDGE_NODE pool and one CLOUD_POD."""
+    orch = Orchestrator(StreamJob("default", dim=8))
+    default = cm.ClusterSpec.edge_cloud()
+    assert dict(orch._base_cluster.pools) == dict(default.pools)
+    assert orch.cluster.pools == {"edge": cm.EDGE_NODE,
+                                  "cloud": cm.CLOUD_POD}
+    assert dict(orch.controller.resources.pools) == dict(default.pools)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Polynomial DP placement (ROADMAP item 5): differential tests of
-``place_frontier_dp`` against the exhaustive oracles, the dispatch
-policy, the exhaustive-oracle size caps, codec tie/dedup regressions,
-placement edge cases, and the measured-operator-cost loop."""
+``place_frontier`` against the exhaustive oracles, the exhaustive-oracle
+size cap, codec tie/dedup regressions, placement edge cases, and the
+measured-operator-cost loop."""
 
 import numpy as np
 import pytest
@@ -9,13 +9,12 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import costmodel as cm
-from repro.core import placement as P
 from repro.core import selftune
 from repro.core.offload import OffloadController
 from repro.core.orchestrator import Orchestrator, StreamJob
 from repro.core.pipeline import Op, OpGraph, fanout_stream_graph
-from repro.core.placement import (Objective, frontier_plans, place_exhaustive,
-                                  place_frontier, place_frontier_dp,
+from repro.core.placement import (Objective, frontier_plans, place_frontier,
+                                  place_frontier_oracle,
                                   place_graph_exhaustive)
 from repro.core.sla import SLA
 from repro.streams.generators import HyperplaneStream
@@ -105,9 +104,8 @@ def test_dp_matches_full_oracle_on_random_dags():
     for seed in range(12):
         g = random_graph(np.random.default_rng(seed), 2 + seed % 4)
         rate = float(rng.choice([1e3, 1e4, 1e5]))
-        plan_dp, frontier_dp = place_frontier_dp(g, spec, rate, OBJ)
-        plan_en, frontier_en = place_frontier(g, spec, rate, OBJ,
-                                              method="enumerate")
+        plan_dp, frontier_dp = place_frontier(g, spec, rate, OBJ)
+        plan_en, frontier_en = place_frontier_oracle(g, spec, rate, OBJ)
         assert plan_dp.assignment == plan_en.assignment, f"seed {seed}"
         assert frontier_dp == frontier_en, f"seed {seed}"
         oracle = place_graph_exhaustive(g, spec, rate, OBJ)
@@ -123,9 +121,8 @@ def test_dp_matches_enumeration_8ops_plan_identical():
     spec = multipool_spec()
     for seed in (3, 11, 27):
         g = random_graph(np.random.default_rng(seed), 8)
-        plan_dp, frontier_dp = place_frontier_dp(g, spec, 2e4, OBJ)
-        plan_en, frontier_en = place_frontier(g, spec, 2e4, OBJ,
-                                              method="enumerate")
+        plan_dp, frontier_dp = place_frontier(g, spec, 2e4, OBJ)
+        plan_en, frontier_en = place_frontier_oracle(g, spec, 2e4, OBJ)
         assert plan_dp.assignment == plan_en.assignment, f"seed {seed}"
         assert frontier_dp == frontier_en
         assert plan_dp.uplink_codec == plan_en.uplink_codec
@@ -139,9 +136,8 @@ def test_dp_codec_ladder_matches_enumeration():
     codecs = ["topk_int8_ef", "identity", "int8_ef"]   # adverse order
     for seed in (1, 5, 9, 16):
         g = random_graph(np.random.default_rng(seed), 3 + seed % 4)
-        plan_dp, f_dp = place_frontier_dp(g, spec, 5e4, OBJ, codecs)
-        plan_en, f_en = place_frontier(g, spec, 5e4, OBJ, codecs,
-                                       method="enumerate")
+        plan_dp, f_dp = place_frontier(g, spec, 5e4, OBJ, codecs)
+        plan_en, f_en = place_frontier_oracle(g, spec, 5e4, OBJ, codecs)
         assert plan_dp.assignment == plan_en.assignment, f"seed {seed}"
         assert plan_dp.uplink_codec == plan_en.uplink_codec, f"seed {seed}"
         assert f_dp == f_en
@@ -153,7 +149,7 @@ def test_dp_small_cases_certified_exact():
     spec = multipool_spec()
     g = random_graph(np.random.default_rng(2), 6)
     stats = {}
-    place_frontier_dp(g, spec, 1e4, OBJ, stats=stats)
+    place_frontier(g, spec, 1e4, OBJ, stats=stats)
     assert stats["truncated"] is False
     assert 0 < stats["labels_peak"] <= 4096
     assert stats["labels_expanded"] > 0
@@ -166,70 +162,21 @@ def test_dp_beam_degrades_loudly_not_silently():
     spec = multipool_spec()
     g = random_graph(np.random.default_rng(0), 8)
     stats = {}
-    plan, frontier = place_frontier_dp(g, spec, 1e4, OBJ, max_labels=1,
-                                       stats=stats)
+    plan, frontier = place_frontier(g, spec, 1e4, OBJ, max_labels=1,
+                                    stats=stats)
     assert stats["truncated"] is True
     assert set(plan.assignment) == set(g.names)
-    exact, _ = place_frontier_dp(g, spec, 1e4, OBJ)
+    exact, _ = place_frontier(g, spec, 1e4, OBJ)
     assert OBJ.score(exact) <= OBJ.score(plan) * 1.0001
 
 
 # ---------------------------------------------------------------------------
-# dispatch policy
+# satellite: exhaustive-oracle size cap
 # ---------------------------------------------------------------------------
-
-def test_auto_dispatch_small_graph_stays_on_enumeration(monkeypatch):
-    def boom(*a, **k):  # pragma: no cover - would mean dispatch is wrong
-        raise AssertionError("DP must not run for a small graph")
-    monkeypatch.setattr(P, "place_frontier_dp", boom)
-    g = fanout_stream_graph(dim=8)
-    plan, frontier = place_frontier(g, multipool_spec(), 1e4, OBJ)
-    assert set(plan.assignment) == set(g.names)
-
-
-def test_auto_dispatch_large_graph_routes_to_dp(monkeypatch):
-    calls = {}
-    real = P.place_frontier_dp
-
-    def spy(*a, **k):
-        calls["dp"] = True
-        return real(*a, **k)
-    monkeypatch.setattr(P, "place_frontier_dp", spy)
-    g = chain_graph(30)
-    plan, frontier = place_frontier(g, multipool_spec(), 1e4, OBJ)
-    assert calls.get("dp") is True
-    assert set(plan.assignment) == set(g.names)
-    # and the explicit engines agree on what they return
-    plan_dp, f_dp = real(g, multipool_spec(), 1e4, OBJ)
-    assert plan_dp.assignment == plan.assignment
-    assert f_dp == frontier
-
-
-def test_method_validation():
-    g = fanout_stream_graph(dim=8)
-    with pytest.raises(ValueError, match="method"):
-        place_frontier(g, multipool_spec(), 1e4, OBJ, method="guess")
-
-
-# ---------------------------------------------------------------------------
-# satellite: exhaustive-oracle size caps
-# ---------------------------------------------------------------------------
-
-def test_place_exhaustive_size_cap():
-    ops = [cm.OperatorCost(f"s{i}", 1e3, 64, 32) for i in range(40)]
-    with pytest.raises(ValueError, match=r"would enumerate .*~1e"):
-        place_exhaustive(ops, {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD},
-                         1e4, OBJ)
-    # explicit opt-in raises the cap
-    plan = place_exhaustive(ops[:4],
-                            {"edge": cm.EDGE_NODE, "cloud": cm.CLOUD_POD},
-                            1e4, OBJ, max_states=100)
-    assert set(plan.assignment) == {f"s{i}" for i in range(4)}
-
 
 def test_place_graph_exhaustive_size_cap():
     g = chain_graph(40)
-    with pytest.raises(ValueError, match="max_states"):
+    with pytest.raises(ValueError, match=r"would enumerate .*~1e.*max_states"):
         place_graph_exhaustive(g, multipool_spec(), 1e4, OBJ)
     small = chain_graph(3)
     plan = place_graph_exhaustive(small, multipool_spec(), 1e4, OBJ,
@@ -258,27 +205,25 @@ def test_frontier_plans_no_duplicate_frontiers_under_codec_ties():
 
 def test_codec_score_ties_resolve_most_faithful_first():
     """A plan with no uplink crossing scores identically under every
-    codec: both engines must pick the most faithful candidate, whatever
-    order the candidates were passed in."""
+    codec: the DP and its oracle must pick the most faithful candidate,
+    whatever order the candidates were passed in."""
     g = chain_graph(3)
     # roomy edge: everything fits on the edge pool, no crossing
     edge = cm.Resource("edge", "edge", chips=4, flops=1e13, mem_bw=8e11,
                        mem_cap=64e9, net_bw=1e10, energy_w=10.0)
     spec = cm.ClusterSpec(pools=[edge, cm.CLOUD_POD])
-    for method in ("enumerate", "dp"):
-        plan, frontier = place_frontier(
-            g, spec, 1e3, OBJ, codecs=["topk_int8_ef", "int8_ef", "identity"],
-            method=method)
-        assert frontier == frozenset(g.names), method
-        assert plan.uplink_codec == "identity", method
+    for place in (place_frontier, place_frontier_oracle):
+        plan, frontier = place(
+            g, spec, 1e3, OBJ, codecs=["topk_int8_ef", "int8_ef", "identity"])
+        assert frontier == frozenset(g.names), place.__name__
+        assert plan.uplink_codec == "identity", place.__name__
 
 
 def test_unknown_codec_name_raises():
     g = chain_graph(3)
-    for method in ("enumerate", "dp"):
+    for place in (place_frontier, place_frontier_oracle):
         with pytest.raises(ValueError):
-            place_frontier(g, multipool_spec(), 1e4, OBJ,
-                           codecs=["no_such_codec"], method=method)
+            place(g, multipool_spec(), 1e4, OBJ, codecs=["no_such_codec"])
 
 
 # ---------------------------------------------------------------------------
@@ -290,11 +235,9 @@ def test_single_kind_cluster_raises_for_both_engines():
     edge_only = cm.ClusterSpec(pools=[cm.EDGE_NODE])
     cloud_only = cm.ClusterSpec(pools=[cm.CLOUD_POD])
     for spec in (edge_only, cloud_only):
-        for method in ("enumerate", "dp"):
+        for place in (place_frontier, place_frontier_oracle):
             with pytest.raises(ValueError, match="at least one"):
-                place_frontier(g, spec, 1e4, OBJ, method=method)
-        with pytest.raises(ValueError, match="at least one"):
-            place_frontier_dp(g, spec, 1e4, OBJ)
+                place(g, spec, 1e4, OBJ)
 
 
 def test_disconnected_components_agree():
@@ -310,8 +253,8 @@ def test_disconnected_components_agree():
                           writes=[f"{comp}k{j}"], cost=cost))
     g = OpGraph(ops)
     spec = multipool_spec()
-    plan_dp, f_dp = place_frontier_dp(g, spec, 1e4, OBJ)
-    plan_en, f_en = place_frontier(g, spec, 1e4, OBJ, method="enumerate")
+    plan_dp, f_dp = place_frontier(g, spec, 1e4, OBJ)
+    plan_en, f_en = place_frontier_oracle(g, spec, 1e4, OBJ)
     assert plan_dp.assignment == plan_en.assignment
     assert f_dp == f_en
 
@@ -322,48 +265,37 @@ def test_edge_incapable_root_forces_all_cloud():
     find it rather than an infeasible edge placement."""
     g = chain_graph(4, edge_capable_all=False)   # op0 edge_capable=False
     spec = multipool_spec()
-    for method in ("enumerate", "dp"):
-        plan, frontier = place_frontier(g, spec, 1e4, OBJ, method=method)
-        assert frontier == frozenset(), method
-        assert plan.feasible, method
+    for place in (place_frontier, place_frontier_oracle):
+        plan, frontier = place(g, spec, 1e4, OBJ)
+        assert frontier == frozenset(), place.__name__
+        assert plan.feasible, place.__name__
         assert all(spec[p].kind == "cloud"
-                   for p in plan.assignment.values()), method
+                   for p in plan.assignment.values()), place.__name__
 
 
 # ---------------------------------------------------------------------------
 # controller integration
 # ---------------------------------------------------------------------------
 
-def _controller(method):
-    g = fanout_stream_graph(dim=8)
-    sla = SLA(max_latency_s=1e3, error_budget=11.0)
-    return OffloadController(g.costs(), multipool_spec(), graph=g,
-                             codec="topk_int8_ef", sla_spec=sla,
-                             cooldown=1, codec_cooldown=1,
-                             placement_method=method)
-
-
-def test_controller_defaults_to_dp():
-    g = fanout_stream_graph(dim=8)
-    ctl = OffloadController(g.costs(), multipool_spec(), graph=g)
-    assert ctl.placement_method == "dp"
-
-
 def test_controller_dp_vs_enumerate_identical_histories():
-    """The DP default must not change a single control decision: same
-    rate trace -> same assignments, codecs, reasons, migration count."""
-    ctls = {m: _controller(m) for m in ("dp", "enumerate")}
-    rates = [5e6, 1e3, 5e6, 1e3, 5e6, 2e4, 5e6, 1e3]
-    for ctl in ctls.values():
-        ctl.initial_plan(5e6)
-        for step, rate in enumerate(rates):
-            ctl.observe(step, rate)
-    dp, en = ctls["dp"], ctls["enumerate"]
-    assert dp.migrations() == en.migrations()
-    assert [(d.reason, d.codec, tuple(sorted(d.assignment.items())))
-            for d in dp.history] == \
-           [(d.reason, d.codec, tuple(sorted(d.assignment.items())))
-            for d in en.history]
+    """Every plan the controller adopts along a rate trace (with codec
+    re-admission on) is the frontier oracle's plan at that decision's
+    rate under the codec it took: same assignment, same frontier."""
+    g = fanout_stream_graph(dim=8)
+    spec = multipool_spec()
+    ctl = OffloadController(g, spec, codec="topk_int8_ef",
+                            sla_spec=SLA(max_latency_s=1e3,
+                                         error_budget=11.0),
+                            cooldown=1, codec_cooldown=1)
+    ctl.initial_plan(5e6)
+    for step, rate in enumerate([5e6, 1e3, 5e6, 1e3, 5e6, 2e4, 5e6, 1e3]):
+        ctl.observe(step, rate)
+    assert ctl.migrations() > 0
+    for d in ctl.history:
+        plan, frontier = place_frontier_oracle(g, spec, d.rate, OBJ,
+                                               codecs=[d.codec])
+        assert d.assignment == plan.assignment, (d.step, d.reason)
+        assert d.frontier == frontier, (d.step, d.reason)
 
 
 # ---------------------------------------------------------------------------
@@ -446,9 +378,8 @@ def test_dp_matches_enumeration_under_link_energy():
         spec = energy_spec(scale)
         for seed in (1, 5, 13, 21):
             g = random_graph(np.random.default_rng(seed), 6)
-            plan_dp, f_dp = place_frontier_dp(g, spec, 1e4, obj)
-            plan_en, f_en = place_frontier(g, spec, 1e4, obj,
-                                           method="enumerate")
+            plan_dp, f_dp = place_frontier(g, spec, 1e4, obj)
+            plan_en, f_en = place_frontier_oracle(g, spec, 1e4, obj)
             assert plan_dp.assignment == plan_en.assignment, \
                 f"scale {scale} seed {seed}"
             assert f_dp == f_en
@@ -464,7 +395,7 @@ def test_dp_energy_term_matches_evaluator_repricing():
     obj = Objective(latency_weight=1.0, energy_weight=25.0)
     for seed in (2, 9):
         g = random_graph(np.random.default_rng(seed), 7)
-        plan_dp, _ = place_frontier_dp(g, spec, 1e4, obj)
+        plan_dp, _ = place_frontier(g, spec, 1e4, obj)
         repriced = cm.evaluate_graph_plan(
             g.costs(), g.flow_edges, plan_dp.assignment, spec, 1e4,
             source_consumers=g.source_consumers,

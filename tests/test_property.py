@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.core import pipeline as pl
 from repro.core.costmodel import CLOUD_POD, EDGE_NODE, OperatorCost
 from repro.core.placement import (Objective, place_frontier,
+                                  place_frontier_oracle,
                                   place_graph_exhaustive)
 from repro.dist.api import logical_to_spec
 from repro.dist.compression import dequantize_int8, quantize_int8
@@ -148,13 +149,13 @@ def test_pipeline_every_cut_bitwise_matches_reference(kind, dim, nbatches,
         for bd in data:
             bd = dict(bd)
             bd["rng"] = rng
-            states, out = pipe.run(states, bd, cut)
+            states, out = pipe.run(states, bd, pipe.names[:cut])
             rng = out["rng"]
             outs.append(out)
         return states, outs
 
     ref_states, ref_outs = run(0)
-    for cut in range(1, pipe.n_cuts):
+    for cut in range(1, len(pipe.names) + 1):
         states, outs = run(cut)
         for a, b in zip(jax.tree.leaves((ref_states, ref_outs)),
                         jax.tree.leaves((states, outs))):
@@ -228,14 +229,15 @@ def _random_dag(draw):
 @settings(max_examples=60, deadline=None, database=None)
 @given(case=_random_dag())
 def test_frontier_search_matches_exhaustive_oracle_on_random_dags(case):
-    """Frontier-cut (downward-closed) placement search must find the same
-    best score as the exhaustive all-assignments oracle on random small
-    DAGs — backhaul-free assignments ARE the frontier cuts, so searching
-    only antichain cuts loses nothing."""
+    """The frontier-cut (downward-closed) enumeration the DP is checked
+    against must find the same best score as the exhaustive
+    all-assignments oracle on random small DAGs — backhaul-free
+    assignments ARE the frontier cuts, so searching only antichain cuts
+    loses nothing."""
     graph, rate = case
     obj = Objective()
     res = {"edge": EDGE_NODE, "cloud": CLOUD_POD}
-    best, frontier = place_frontier(graph, res, rate, obj)
+    best, frontier = place_frontier_oracle(graph, res, rate, obj)
     oracle = place_graph_exhaustive(graph, res, rate, obj)
     assert obj.score(best) <= obj.score(oracle) * 1.0001, (
         f"frontier search lost to the oracle: frontier={sorted(frontier)} "
@@ -264,14 +266,14 @@ def _multipool_spec(codec: str):
 def test_multipool_frontier_search_matches_oracle_on_random_dags(case, codec):
     """The multi-pool generalization of the invariant above: over a
     2-edge-pool/2-cloud-pod ClusterSpec with codec-carrying links, the
-    frontier search (frontiers x within-kind pool assignments) must match
+    frontier oracle (frontiers x within-kind pool assignments) must match
     the exhaustive every-op-to-every-pool oracle — cloud->edge backhaul
     stays infeasible, so the edge-resident set of any feasible assignment
     is downward-closed and the search covers it."""
     graph, rate = case
     obj = Objective()
     spec = _multipool_spec(codec)
-    best, frontier = place_frontier(graph, spec, rate, obj)
+    best, frontier = place_frontier_oracle(graph, spec, rate, obj)
     oracle = place_graph_exhaustive(graph, spec, rate, obj)
     assert obj.score(best) <= obj.score(oracle) * 1.0001, (
         f"multi-pool frontier search lost to the oracle: "
@@ -286,13 +288,12 @@ def test_multipool_frontier_search_matches_oracle_on_random_dags(case, codec):
 @given(case=_random_dag())
 def test_dp_placement_matches_oracle_on_random_dags(case):
     """The polynomial label DP must equal the exhaustive all-assignments
-    oracle on random small DAGs — same invariant the enumeration engine
-    carries, now for the engine real problem sizes run on."""
-    from repro.core.placement import place_frontier_dp
+    oracle on random small DAGs — the invariant its frontier oracle
+    carries, for the engine every plan comes from."""
     graph, rate = case
     obj = Objective()
     res = {"edge": EDGE_NODE, "cloud": CLOUD_POD}
-    best, frontier = place_frontier_dp(graph, res, rate, obj)
+    best, frontier = place_frontier(graph, res, rate, obj)
     oracle = place_graph_exhaustive(graph, res, rate, obj)
     assert obj.score(best) <= obj.score(oracle) * 1.0001, (
         f"DP lost to the oracle: frontier={sorted(frontier)} "
@@ -305,18 +306,16 @@ def test_dp_placement_matches_oracle_on_random_dags(case):
        codec=st.sampled_from(["identity", "int8_ef", "topk_int8_ef"]))
 def test_dp_multipool_codec_ladder_matches_enumeration(case, codec):
     """Multi-pool + codec-candidate generalization: the DP must return
-    the exact plan (assignment, frontier, codec) the enumeration engine
-    returns — not just the score — so the two engines are
-    interchangeable inside the offload controller."""
-    from repro.core.placement import place_frontier_dp
+    the exact plan (assignment, frontier, codec) the frontier oracle
+    returns — not just the score."""
     graph, rate = case
     obj = Objective()
     spec = _multipool_spec(codec)
     codecs = ["topk_int8_ef", codec, "identity"]
-    best_dp, frontier_dp = place_frontier_dp(graph, spec, rate, obj,
-                                             codecs=codecs)
-    best_en, frontier_en = place_frontier(graph, spec, rate, obj,
-                                          codecs=codecs, method="enumerate")
+    best_dp, frontier_dp = place_frontier(graph, spec, rate, obj,
+                                          codecs=codecs)
+    best_en, frontier_en = place_frontier_oracle(graph, spec, rate, obj,
+                                                 codecs=codecs)
     assert best_dp.assignment == best_en.assignment, (
         f"DP/enumeration diverged: dp={best_dp.assignment} "
         f"en={best_en.assignment}")
