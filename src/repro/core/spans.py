@@ -26,6 +26,13 @@ nothing at run time:
   program without it reads each layer's weights in place from the stack.
   The lowered program's locations name it; the compiler may drop the
   barrier itself from the compiled text.
+- ``s2ce.scan.cache_write``: the layer scan's write of each layer's new
+  positions into a position-indexed cache stack (KV, latent) that the
+  scan carries (``models/transformer.py``). It is there exactly where a
+  stack holds such a cache, in prefill and decode, with or without a
+  mesh, and absent where a stack holds only recurrent states; its
+  presence means the stack is updated in place at the layer's length
+  and no layer's whole slice is written back.
 """
 
 import jax

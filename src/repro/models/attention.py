@@ -228,7 +228,9 @@ def _project(p, cfg, x, name):
 def self_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
                    cache: Optional[KVCache] = None, causal: bool = True,
                    impl: str = "chunked"):
-    """x: (B,S,D). Returns (out, new_cache)."""
+    """x: (B,S,D). Returns (out, new): with a cache, ``new`` holds the S
+    new positions of each cache field (``(k, v)``), which ``append`` writes
+    at the cache's length; without one it is None."""
     q = _project(p, cfg, x, "q")
     k = _project(p, cfg, x, "k")
     v = _project(p, cfg, x, "v")
@@ -237,17 +239,16 @@ def self_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
         k = apply_rope(k, positions, cfg.rope_theta)
     q = shard(q, "batch", None, "heads", None)
 
-    new_cache = None
+    new = None
     kv_len = None
     if isinstance(positions, jax.Array):
         q_offset = positions[:, 0] if positions.ndim == 2 else positions[0]
     else:
         q_offset = positions
     if cache is not None:
-        k_all = jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), cache.length, axis=1)
-        v_all = jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), cache.length, axis=1)
-        new_cache = KVCache(k_all, v_all, cache.length + k.shape[1])
-        k, v = k_all.astype(x.dtype), v_all.astype(x.dtype)
+        new = (k, v)
+        full = append(cache, new)
+        k, v = full.k.astype(x.dtype), full.v.astype(x.dtype)
         kv_len = cache.length + q.shape[1]
         q_offset = cache.length
     rep = kv_repeat_factor(cfg)
@@ -258,7 +259,17 @@ def self_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
                   q_offset=q_offset, kv_len=kv_len)
     o = shard(o, "batch", None, "heads", None)
     out = jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype))
-    return out, new_cache
+    return out, new
+
+
+def append(cache, new):
+    """``cache`` (a ``KVCache`` or ``MLACache``) with the positions ``new``
+    holds, one array a field in the cache's order, written at its length:
+    one dynamic_update_slice a field."""
+    fields = [jax.lax.dynamic_update_slice_in_dim(a, u.astype(a.dtype),
+                                                  cache.length, axis=1)
+              for a, u in zip(cache, new)]
+    return type(cache)(*fields, cache.length + new[0].shape[1])
 
 
 def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> KVCache:
@@ -295,7 +306,8 @@ def mla_attention(p, cfg: ArchConfig, x: jax.Array, *, positions,
                   cache: Optional[MLACache] = None, impl: str = "chunked"):
     """Latent attention. A decode step (one new position against a cache)
     scores in the latent space; every other call expands the latent into
-    per-head keys and values."""
+    per-head keys and values. Returns (out, new) as ``self_attention``
+    does, ``new`` being ``(c_kv, k_rope)`` of the S new positions."""
     decode = cache is not None and x.shape[1] == 1
     with jax.named_scope("s2ce.mla.decode_latent" if decode
                          else "s2ce.mla.prefill"):
@@ -331,15 +343,13 @@ def _mla(p, cfg: ArchConfig, x, *, positions, cache, impl, decode):
 
     q_offset = 0
     kv_len = None
+    new = None
     if cache is not None:
-        c_all = jax.lax.dynamic_update_slice_in_dim(cache.c_kv, c.astype(cache.c_kv.dtype), cache.length, axis=1)
-        kr_all = jax.lax.dynamic_update_slice_in_dim(cache.k_rope, kr.astype(cache.k_rope.dtype), cache.length, axis=1)
-        new_cache = MLACache(c_all, kr_all, cache.length + S)
-        c, kr = c_all.astype(x.dtype), kr_all.astype(x.dtype)
+        new = (c, kr)
+        full = append(cache, new)
+        c, kr = full.c_kv.astype(x.dtype), full.k_rope.astype(x.dtype)
         kv_len = cache.length + S
         q_offset = cache.length
-    else:
-        new_cache = None
 
     if decode:
         # the query absorbs W_uk, the context leaves through W_uv: scores
@@ -357,8 +367,7 @@ def _mla(p, cfg: ArchConfig, x, *, positions, cache, impl, decode):
                          c.astype(f32))
         o = jnp.einsum("bshr,rhe->bshe", ctx.astype(x.dtype),
                        p["w_uv"].astype(x.dtype))
-        return jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype)), \
-            new_cache
+        return jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype)), new
 
     k_nope = jnp.einsum("btr,rhe->bthe", c, p["w_uk"].astype(x.dtype))
     vv = jnp.einsum("btr,rhe->bthe", c, p["w_uv"].astype(x.dtype))
@@ -372,7 +381,7 @@ def _mla(p, cfg: ArchConfig, x, *, positions, cache, impl, decode):
     o = attention(qq, k, vv, causal=True, impl=impl, chunk=cfg.attn_chunk,
                   q_offset=q_offset, kv_len=kv_len, scale=scale)
     out = jnp.einsum("bshe,hed->bsd", o, p["wo"].astype(x.dtype))
-    return out, new_cache
+    return out, new
 
 
 def init_mla_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> MLACache:

@@ -167,7 +167,10 @@ def model_specs(cfg: ArchConfig):
 
 def apply_slot(p, cfg: ArchConfig, slot: Slot, x, *, positions, memory,
                cache, impl: str):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, update, aux). With a cache, ``update`` maps each of the
+    slot's cache entries to what replaces it, except that a
+    position-indexed cache (``"kv"``) gives only its new positions, which
+    the caller writes in place (``_write_update``)."""
     aux = jnp.zeros((), jnp.float32)
     # norm on the seq-sharded residual (fp32 internals stay 1/16-seq),
     # then gather the bf16 norm output for the TP matmuls
@@ -327,10 +330,45 @@ def _layer_params(lp, axes):
     return _constrain_layer_params(lp, axes)
 
 
+# names the write of each layer's new positions into the carried cache
+# stack, present exactly where the scan carries a position-indexed cache
+# (listed in ``repro.core.spans``)
+CACHE_WRITE = "s2ce.scan.cache_write"
+
+
+def _positional(cache) -> bool:
+    """A cache that grows by writing new positions at its length."""
+    return isinstance(cache, (attn.KVCache, attn.MLACache))
+
+
+def _write_update(cache, update):
+    """A slot's cache after ``apply_slot``: new positions appended to each
+    position-indexed cache, every other state replaced."""
+    return {k: attn.append(cache[k], u) if _positional(cache.get(k)) else u
+            for k, u in update.items()}
+
+
+def _write_layer(stack, new, i):
+    """A stacked position-indexed cache with layer ``i``'s new positions
+    written at that layer's length: one dynamic_update_slice a field, so
+    no layer's whole slice is written back."""
+    at = stack.length[i]
+    fields = [jax.lax.dynamic_update_slice(
+        a, u.astype(a.dtype)[None], (i, 0, at) + (0,) * (a.ndim - 3))
+        for a, u in zip(stack, new)]
+    length = jax.lax.dynamic_update_index_in_dim(
+        stack.length, at + new[0].shape[1], i, 0)
+    return type(stack)(*fields, length)
+
+
 def run_stack(params, cfg: ArchConfig, pattern, x, *, positions, memory,
               caches, impl, stack_axes=None):
-    """params: stacked slot-param list; caches: stacked cache trees or None."""
-    n_slots = len(pattern)
+    """params: stacked slot-param list; caches: stacked cache trees or None.
+
+    Position-indexed caches (KV, latent) ride the scan's carry: layer ``i``
+    reads its slice of the stack and writes only its new positions back,
+    in place. Every other state (recurrent states, cross caches) is
+    replaced whole, so it enters as ``xs`` and leaves as ``ys``."""
 
     def body(x, layer_params, layer_caches):
         aux = jnp.zeros((), jnp.float32)
@@ -359,12 +397,31 @@ def run_stack(params, cfg: ArchConfig, pattern, x, *, positions, memory,
         x, auxs = jax.lax.scan(scan_body, x, params)
         return x, None, jnp.sum(auxs)
 
-    def scan_body(x, xs):
-        lp, lc = xs
-        x, nc, aux = body(x, _layer_params(lp, stack_axes), lc)
-        return x, (nc, aux)
-    x, (new_caches, auxs) = jax.lax.scan(scan_body, x, (params, caches))
-    return x, new_caches, jnp.sum(auxs)
+    from repro.models.model_zoo import constrain_caches
+    stacks = [{k: c for k, c in slot.items() if _positional(c)}
+              for slot in caches]
+    states = [{k: c for k, c in slot.items() if not _positional(c)}
+              for slot in caches]
+    n = jax.tree.leaves(params)[0].shape[0]
+
+    def scan_body(carry, xs):
+        x, stacks = carry
+        lp, lc, i = xs
+        layer = [dict(st, **jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), sk))
+            for sk, st in zip(stacks, lc)]
+        x, upd, aux = body(x, _layer_params(lp, stack_axes), layer)
+        with jax.named_scope(CACHE_WRITE):
+            stacks = constrain_caches(
+                [{k: _write_layer(c, u[k], i) for k, c in sk.items()}
+                 for sk, u in zip(stacks, upd)])
+        rest = [{k: v for k, v in u.items() if k not in sk}
+                for sk, u in zip(stacks, upd)]
+        return (x, stacks), (rest, aux)
+
+    (x, stacks), (rest, auxs) = jax.lax.scan(
+        scan_body, (x, stacks), (params, states, jnp.arange(n)))
+    return x, [dict(r, **sk) for r, sk in zip(rest, stacks)], jnp.sum(auxs)
 
 
 def run_prefix(params, cfg: ArchConfig, slots, x, *, positions, memory,
@@ -373,9 +430,9 @@ def run_prefix(params, cfg: ArchConfig, slots, x, *, positions, memory,
     new_caches = []
     for i, slot in enumerate(slots):
         c = caches[i] if caches is not None else None
-        x, nc, a = apply_slot(params[i], cfg, slot, x, positions=positions,
-                              memory=memory, cache=c, impl=impl)
-        new_caches.append(nc)
+        x, upd, a = apply_slot(params[i], cfg, slot, x, positions=positions,
+                               memory=memory, cache=c, impl=impl)
+        new_caches.append(_write_update(c, upd) if c is not None else None)
         aux = aux + a
     return x, (new_caches if caches is not None else None), aux
 

@@ -99,8 +99,9 @@ def test_latent_decode_equals_the_expanded_form():
                                         decode=decode)
     (lat, c_lat), (exp, c_exp) = run(True), run(False)
     assert rel_err(lat, exp) < TOL
-    np.testing.assert_array_equal(c_lat.c_kv, c_exp.c_kv)
-    assert int(c_lat.length) == 10
+    for a, b in zip(c_lat, c_exp):          # the new positions written
+        np.testing.assert_array_equal(a, b)
+    assert int(attention.append(cache, c_lat).length) == 10
 
 
 def test_programs_name_their_latent_attention_and_expert_work():
@@ -319,3 +320,97 @@ def test_param_counts_count_the_held_share():
     assert cut.param_counts()["total"] + router + norms == \
         zoo.param_count(cut)
     assert 3.110e9 < zoo.param_count(cut) < 3.112e9     # 3.111 B held
+
+
+# -- the layer scan's cache write ---------------------------------------------
+
+MESHES = pytest.mark.parametrize("mesh", [None, {"data": 2, "model": 2}],
+                                 ids=["no_mesh", "fsdp_2x2"])
+
+
+def _under(mesh, arch):
+    import contextlib
+    from repro.dist import use_mesh
+    from repro.dist.sharding import build_rules
+    return (use_mesh(mesh, build_rules(arch, recipe="fsdp")) if mesh
+            else contextlib.nullcontext())
+
+
+@MESHES
+@pytest.mark.parametrize("arch_id", ["qwen2-1.5b", "deepseek-v2-lite-16b",
+                                     "rwkv6-1.6b"])
+def test_scan_names_its_cache_write_where_it_carries_a_positional_cache(
+        arch_id, mesh):
+    """``s2ce.scan.cache_write`` is in the lowered prefill and decode of a
+    stack of KV (GQA) or latent (MLA) caches, with or without a mesh, and
+    absent from a stack of only recurrent states (RWKV)."""
+    from repro.configs import get_config
+    from repro.models import model_zoo as zoo
+    arch = get_config(arch_id, smoke=True)
+    params = zoo.param_shapes(arch)
+    caches = jax.eval_shape(lambda: zoo.init_caches(arch, 2, 8))
+    sds = jax.ShapeDtypeStruct
+    with _under(mesh, arch):
+        progs = {
+            "prefill": jax.jit(lambda p, t: zoo.prefill(
+                p, arch, {"tokens": t}, max_len=8))
+            .lower(params, sds((2, 5), jnp.int32)),
+            "decode": jax.jit(lambda p, c, t: zoo.decode_step(p, arch, c, t))
+            .lower(params, caches, sds((2, 1), jnp.int32))}
+    want = arch_id != "rwkv6-1.6b"
+    for name, lowered in progs.items():
+        text = lowered.as_text(debug_info=True)
+        assert ("s2ce.scan.cache_write" in text) == want, name
+
+
+@MESHES
+@pytest.mark.parametrize("arch_id", ["qwen2-1.5b", "deepseek-v2-lite-16b"])
+def test_decode_steps_match_forward_and_fill_the_cache_in_place(arch_id,
+                                                                mesh):
+    """A prefill, then eight decode steps: each step's logits match
+    ``forward_lm`` at that position; every layer's cache keeps the
+    prefill's entries bit for bit, holds each step's at its position and
+    zeros past the filled length; the cache length advances by one a
+    step."""
+    from repro.configs import get_config
+    from repro.models import model_zoo as zoo
+    arch = get_config(arch_id, smoke=True)
+    params = zoo.init_params(arch, seed=4)
+    B, S, N, T = 2, 7, 8, 20
+    tokens = jnp.asarray(np.random.default_rng(4).integers(
+        0, arch.vocab_size, (B, S + N)), jnp.int32)
+    with _under(mesh, arch):
+        full, _ = jax.jit(lambda p, t: zoo.forward_lm(
+            p, arch, {"tokens": t}))(params, tokens)
+        prefill = jax.jit(lambda p, t: zoo.prefill(p, arch, {"tokens": t},
+                                                   max_len=T))
+        decode = jax.jit(lambda p, c, t: zoo.decode_step(p, arch, c, t))
+        _, caches = prefill(params, tokens[:, :S])
+        _, want = prefill(params, tokens)      # every position at once
+        first = jax.tree.map(np.asarray, caches)
+        for j in range(N):
+            assert int(zoo._cache_length(caches)) == S + j
+            logits, caches = decode(params, caches, tokens[:, S + j:S + j + 1])
+            np.testing.assert_allclose(
+                np.asarray(logits[:, 0]), np.asarray(full[:, S + j]),
+                rtol=2e-2, atol=2e-2)
+        assert int(zoo._cache_length(caches)) == S + N
+
+    def fields(c):
+        return [a for name, a in c["kv"]._asdict().items() if name != "length"]
+
+    # the position axis: 1 in a prefix layer's cache, 2 in a stacked one
+    slots = [(got, before, ref, axis)
+             for part, axis in (("prefix", 1), ("stack", 2))
+             for got, before, ref in zip(caches[part], first[part], want[part])]
+    assert slots
+    for got, before, ref, axis in slots:
+        assert np.all(np.asarray(got["kv"].length) == S + N)
+        for g, b, r in zip(fields(got), fields(before), fields(ref)):
+            g, b, r = (np.asarray(a, np.float32) for a in (g, b, r))
+            span = lambda lo, hi=None: (slice(None),) * axis + (  # noqa: E731
+                slice(lo, hi),)
+            np.testing.assert_array_equal(g[span(0, S)], b[span(0, S)])
+            np.testing.assert_allclose(g[span(S, S + N)], r[span(S, S + N)],
+                                       rtol=2e-2, atol=2e-2)
+            assert not np.any(g[span(S + N)])

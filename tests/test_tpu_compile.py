@@ -1,6 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e chip at deployment
-widths, and the serving decode step's layer loop reads its weights in
-place.
+widths, and the serving decode step's layer loop reads its weights and
+writes its caches in place.
 
 Nothing runs: each kernel is lowered and compiled for a described (not
 attached) ``v5e:2x2`` topology, so the chip's compiler refuses here what
@@ -221,3 +221,68 @@ def test_decode_layer_loop_reads_weights_in_place(model, one_chip,
         params, caches, tokens).compile().as_text()
     assert "while" in hlo
     assert _weight_slices_in_loops(hlo, params["stack"]) == []
+
+
+def _shapes(line: str) -> set:
+    """(dtype, dims) of each array in an instruction's result."""
+    result, _ = _result_and_opcode(line)
+    return {(d, tuple(int(n) for n in dims.split(",") if n))
+            for d, dims in re.findall(r"(\w+)\[([\d,]*)\]", result)}
+
+
+def _writes(comps: dict, defs: dict, line: str, stacks: set) -> list:
+    """The shapes each stack-shaped result of ``line`` is written with: the
+    update operand of a dynamic-update-slice, or of those of the fusion it
+    calls, by the update's defining instruction; None for a stack-shaped
+    result made any other way (a copy, a relayout)."""
+    _, opcode = _result_and_opcode(line)
+    if opcode == "dynamic-update-slice":
+        update = re.search(r"dynamic-update-slice\(([^)]*)\)", line) \
+            .group(1).split(",")[1].strip()
+        return [_shapes(defs[update])]
+    if opcode == "fusion":
+        called = comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
+        dus = [l for l in called if " = " in l and _shapes(l) & stacks
+               and _result_and_opcode(l)[1] == "dynamic-update-slice"]
+        return [w for l in dus for w in _writes(comps, defs, l, stacks)] \
+            or [None]
+    return [None]
+
+
+@pytest.mark.parametrize("model", sorted(DECODERS))
+def test_decode_writes_cache_in_place(model, one_chip, no_persistent_cache):
+    """Compiled for one chip with the cells' 16 rows x 1,664 positions,
+    the decode step copies no cache stack, and its layer loop writes each
+    stack with the new position alone, never a layer's whole slice."""
+    from repro.models import model_zoo as zoo
+    arch = _small(**DECODERS[model])
+    place = lambda t: jax.tree.map(                         # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    params = place(zoo.param_shapes(arch))
+    caches = place(jax.eval_shape(lambda: zoo.init_caches(arch, 16, 1664)))
+    tokens = jax.ShapeDtypeStruct((16, 1), I32, sharding=one_chip)
+    hlo = jax.jit(lambda p, c, t: zoo.decode_step(p, arch, c, t),
+                  donate_argnums=(1,)).lower(params, caches, tokens) \
+        .compile().as_text()
+    # the position-indexed fields of the stacked caches, (layers, rows,
+    # positions, ...), and the shape one decode step writes into each
+    fields = [a for a in jax.tree.leaves(caches["stack"]) if a.ndim >= 4]
+    assert fields
+    stacks = {(HLO_DTYPES[jnp.dtype(a.dtype).name], tuple(a.shape))
+              for a in fields}
+    new = {(d, (1, s[1], 1) + s[3:]) for d, s in stacks}
+    comps = _computations(hlo)
+    defs = {l.split(" = ", 1)[0].split()[-1]: l
+            for lines in comps.values() for l in lines if " = " in l}
+    copies = [name for name, l in defs.items()
+              if _result_and_opcode(l)[1] in ("copy", "copy-start")
+              and _shapes(l) & stacks]
+    assert copies == []
+    writes = []
+    for body in set(re.findall(r"body=%?([\w.\-]+)", hlo)):
+        for line in comps.get(body, []):
+            if " = " in line and _shapes(line) & stacks \
+                    and _result_and_opcode(line)[1] not in FREE:
+                writes += _writes(comps, defs, line, stacks)
+    assert writes
+    assert all(w is not None and w <= new for w in writes), writes
